@@ -85,4 +85,7 @@ def main(rows: list[str] | None = None) -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("\n".join(main()))

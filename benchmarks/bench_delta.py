@@ -175,4 +175,7 @@ def main(argv: list[str] | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

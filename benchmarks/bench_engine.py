@@ -44,6 +44,7 @@ from repro.engine import (
     QueryEngine,
 )
 from repro.engine.plan import MASKED_ENGINES
+from repro.shard import make_mesh
 
 #: same-generation query over a class hierarchy (paper Query 1 shape,
 #: single label pair to keep |P| small and the workload uniform)
@@ -83,23 +84,23 @@ def parse_mesh(spec: str) -> tuple[int, int]:
 def ensure_host_devices(need: int, module: str, argv: list[str]) -> None:
     """Re-exec ``python -m module argv`` with enough forced host devices.
 
-    XLA fixes the device count at backend init (which module imports
-    already triggered), so the flag cannot be set in-process; when the
-    current process is short, replace it with one that has the flag —
-    stdout (the JSON) passes straight through.  One-shot: if the re-exec
-    still comes up short (e.g. ``JAX_PLATFORMS`` pins a non-CPU backend,
-    where the host-device flag has no effect), error out instead of
-    exec-looping.
+    Only on the CPU backend: XLA fixes the host device count at backend
+    init (which module imports already triggered), so the flag cannot be
+    set in-process; when the current CPU process is short, replace it with
+    one that has the flag — stdout (the JSON) passes straight through.  On
+    any other platform the devices are real and too few is an error, never
+    a quiet switch to the CPU.  One-shot: a re-exec that still comes up
+    short errors out instead of exec-looping.
     """
     import jax
 
     if jax.device_count() >= need:
         return
-    if os.environ.get("_REPRO_MESH_REEXEC"):
+    platform = jax.devices()[0].platform
+    if platform != "cpu" or os.environ.get("_REPRO_MESH_REEXEC"):
         raise SystemExit(
-            f"--mesh needs {need} devices but only {jax.device_count()} are "
-            "visible even after forcing host devices (is JAX_PLATFORMS "
-            "pinned to a non-CPU backend?)"
+            f"--mesh needs {need} devices but only {jax.device_count()} "
+            f"{platform} device(s) are visible"
         )
     env = dict(os.environ)
     flags = env.get("XLA_FLAGS", "")
@@ -122,8 +123,6 @@ def bench_mesh_size(
     """Masked-opt on a (data, model) host mesh vs the single-device masked
     engine, same coalesced single-source batch of either semantics
     (differentially checked).  Shared with bench_single_path."""
-    import jax
-
     g = Grammar.from_text(GRAMMAR).to_cnf()
     graph = community_graph(n)
     n_sources = min(n_sources, n // COMMUNITY)
@@ -131,7 +130,7 @@ def bench_mesh_size(
     queries = [
         Query(g, "S", sources=(m,), semantics=semantics) for m in sources
     ]
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape)
 
     timings: dict[str, tuple[float, float]] = {}
     results: dict[str, list] = {}
@@ -310,4 +309,7 @@ def main(argv: list[str] | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

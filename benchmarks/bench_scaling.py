@@ -224,4 +224,7 @@ def cli(argv: list[str] | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     cli()

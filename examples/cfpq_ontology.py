@@ -13,6 +13,7 @@ import time
 import numpy as np
 
 from repro.baselines import hellings_cfpq
+from repro.compile_cache import enable_compile_cache
 from repro.core import closure
 from repro.core.grammar import query1_grammar, query2_grammar
 from repro.core.graph import paper_table_graph
@@ -22,6 +23,7 @@ from repro.core.matrices import (
     relations_from_matrix,
 )
 
+enable_compile_cache()
 name = sys.argv[1] if len(sys.argv) > 1 else "wine"
 graph = paper_table_graph(name)
 print(f"graph {name}: {graph.n_nodes} nodes, {graph.n_edges} edges")

@@ -8,9 +8,12 @@ semantics (Section 5) to extract witness paths.
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.grammar import Grammar
 from repro.core.graph import Graph
 from repro.core.semantics import evaluate_relational, evaluate_single_path
+
+enable_compile_cache()
 
 # The same-generation query (paper Fig. 3) in the natural (non-CNF) form —
 # the CNF transform is part of the frontend.

@@ -1,1 +1,2 @@
+from .cyk import cyk_recognize  # noqa: F401
 from .hellings import hellings_cfpq  # noqa: F401

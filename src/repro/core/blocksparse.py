@@ -277,7 +277,7 @@ class BlockSparseState:
 # The jitted contraction step: one bucket of occupied tile pairs.
 # ---------------------------------------------------------------------- #
 
-_SHIFTS = jnp.arange(32, dtype=jnp.uint32)
+_SHIFTS = np.arange(32, dtype=np.uint32)  # numpy: importing starts no backend
 
 
 @partial(jax.jit, static_argnames=("n_out", "use_kernel"))

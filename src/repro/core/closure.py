@@ -17,10 +17,11 @@ Engines
                      implementation onto the MXU).
   frontier_closure   beyond-paper: incremental evaluation that multiplies only
                      the delta discovered in the previous iteration.
-  bitpacked_closure  uint32 AND/OR words (Pallas kernel on TPU, jnp reference
-                     elsewhere) — the TPU-native adaptation of the paper's
-                     sparse (CSR/CUSPARSE) implementations: 32x smaller HBM
-                     traffic for the memory-bound regime.
+  bitpacked_closure  uint32 AND/OR words (compiled Pallas kernel on TPU;
+                     elsewhere that kernel in interpret mode, or its jnp
+                     oracle at large sizes) — the TPU-native adaptation of
+                     the paper's sparse (CSR/CUSPARSE) implementations: 32x
+                     smaller HBM traffic for the memory-bound regime.
 
 Invariants (relied on by engine/, delta/ and serve/; tested in
 tests/test_engine.py and tests/test_delta.py)
@@ -50,21 +51,31 @@ import jax.numpy as jnp
 
 from .matrices import ProductionTables, pack_bits, unpack_bits
 
-# MXU dtype on TPU; CPU (tests/benches) uses f32 — bf16 matmul is emulated
-# (and slow) on CPU, and the saturation trick is dtype-exact either way.
-_MAT_DTYPE = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
+def _saturating_dot(lhs, rhs, dims, dtype):
+    return jax.lax.dot_general(
+        lhs.astype(dtype),
+        rhs.astype(dtype),
+        dimension_numbers=dims,
+        preferred_element_type=jnp.float32,
+    ) > 0
+
+
+def _mxu_bool_dot(lhs, rhs, dims) -> jnp.ndarray:
+    """``dot(A, B) > 0`` for 0/1 operands — exact with f32 accumulation
+    (any positive count stays positive).  The operand dtype is picked for
+    the platform the caller is lowered for: bf16 feeds the TPU's MXU; other
+    backends use f32, since bf16 matmul is emulated (and slow) on CPU."""
+    return jax.lax.platform_dependent(
+        lhs,
+        rhs,
+        tpu=partial(_saturating_dot, dims=dims, dtype=jnp.bfloat16),
+        default=partial(_saturating_dot, dims=dims, dtype=jnp.float32),
+    )
 
 
 def _bool_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray) -> jnp.ndarray:
-    """Batched Boolean matmul via MXU saturation: dot(A,B) > 0 is exact for
-    0/1 inputs with f32 accumulation (any positive count stays positive)."""
-    prod = jax.lax.dot_general(
-        lhs.astype(_MAT_DTYPE),
-        rhs.astype(_MAT_DTYPE),
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-    return prod > 0
+    """Batched Boolean matmul via MXU saturation."""
+    return _mxu_bool_dot(lhs, rhs, (((2,), (1,)), ((0,), (0,))))
 
 
 def _scatter_or_bool(new_per_prod: jnp.ndarray, tables: ProductionTables):
@@ -645,14 +656,7 @@ def reverse_reachable_mask(
 
     def body(state):
         m, _, it = state
-        hit = (
-            jax.lax.dot(
-                adj.astype(_MAT_DTYPE),
-                m.astype(_MAT_DTYPE)[:, None],
-                preferred_element_type=jnp.float32,
-            )[:, 0]
-            > 0
-        )
+        hit = _mxu_bool_dot(adj, m[:, None], (((1,), (0,)), ((), ())))[:, 0]
         m_next = m | hit
         return m_next, jnp.any(m_next & ~m), it + 1
 

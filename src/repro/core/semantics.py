@@ -44,7 +44,7 @@ from .grammar import CNFGrammar
 from .graph import Graph
 from .matrices import ProductionTables, init_matrix, padded_size
 
-INF = jnp.float32(jnp.inf)
+INF = np.float32(np.inf)  # numpy: importing starts no backend
 
 
 def _minplus(lhs: jnp.ndarray, rhs: jnp.ndarray, chunk: int = 64):
@@ -674,22 +674,20 @@ def masked_bitpacked_conjunctive_closure(
 #: saturation sentinel: a count of 0xFFFFFFFF means ">= 2^32 - 1 paths".
 SAT_COUNT = np.uint32(0xFFFFFFFF)
 
-_SAT = jnp.uint32(0xFFFFFFFF)
-
 
 def _sat_add(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Saturating uint32 add: clamps to the sentinel instead of wrapping.
     Unsigned overflow wrapped iff the wrapped sum is below an operand."""
     s = a + b
-    return jnp.where(s < a, _SAT, s)
+    return jnp.where(s < a, SAT_COUNT, s)
 
 
 def _sat_mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Saturating uint32 multiply: a*b overflows iff b > 0 and
     a > SAT // b.  SAT is absorbing for any b >= 2, and SAT * 1 = SAT,
     so stickiness needs no special casing."""
-    hi = _SAT // jnp.maximum(b, jnp.uint32(1))
-    return jnp.where((b > jnp.uint32(0)) & (a > hi), _SAT, a * b)
+    hi = jnp.floor_divide(SAT_COUNT, jnp.maximum(b, jnp.uint32(1)))
+    return jnp.where((b > jnp.uint32(0)) & (a > hi), SAT_COUNT, a * b)
 
 
 def _count_mm(lhs: jnp.ndarray, rhs: jnp.ndarray, chunk: int = 64):
@@ -844,7 +842,7 @@ def count_closure(
 
     X, _, _ = jax.lax.while_loop(g_cond, g_body, (T, jnp.bool_(True), 0))
 
-    C_seed = jnp.where(X, _SAT, C0)  # phase C: divergent entries pinned
+    C_seed = jnp.where(X, SAT_COUNT, C0)  # phase C: divergent entries pinned
 
     def cond(state):
         _, changed, it = state
@@ -942,7 +940,7 @@ def masked_count_closure(
     )
     # stamp divergent entries (active rows only; invalid lanes write 0 —
     # a no-op under the scatter-max)
-    C = C.at[:, idx, :].max(jnp.where(X_rows, _SAT, zero))
+    C = C.at[:, idx, :].max(jnp.where(X_rows, SAT_COUNT, zero))
 
     # Phase C: saturating Jacobi over the settled active set.
     def cond(state):

@@ -77,6 +77,7 @@ from repro.delta.repair import (
 from repro.delta.txn import EpochClock, Snapshot
 from repro.obs.instruments import EngineMetrics
 from repro.obs.trace import NULL_TRACER, iteration_scope
+from repro.shard.mesh import explicit_axes
 
 from .config import EngineConfig
 from .plan import (
@@ -249,6 +250,16 @@ class QueryEngine:
             raise ValueError(
                 "opt mesh must name 'data' and 'model' axes "
                 f"(got {tuple(config.mesh.axis_names)})"
+            )
+        explicit = () if config.mesh is None else explicit_axes(config.mesh)
+        if explicit:
+            # the sharded closures place operands with
+            # with_sharding_constraint, which refuses Explicit axes — and
+            # would only say so inside the first plan compile
+            raise ValueError(
+                f"opt mesh axes must be Auto, but {explicit} are not; "
+                "build the mesh with repro.shard.make_mesh (or "
+                "jax.make_mesh(..., axis_types=(AxisType.Auto,) * 2))"
             )
         self.graph = graph
         self.config = config

@@ -11,12 +11,21 @@ memory-bound closure regime.  The kernel runs on the VPU (bitwise AND/OR on
 (8,128) vregs); the compute-bound regime is instead served by the MXU
 saturation path in core/closure.py.
 
-Tiling: grid (B, n/TI, w/TW, n/TK); each step loads
+Tiling: grid (B, ceil(m/TI), ceil(w/TW), ceil(k/TK)); each step loads
   lhs block (TI, TK/32)   — contraction bits for TI rows,
   rhs block (TK, TW)      — TK packed rows,
 and accumulates an OR into the resident out block (TI, TW).  The k axis is
 the innermost grid dim so the output block stays in VMEM across the whole
-contraction (standard Pallas accumulation pattern).
+contraction (standard Pallas accumulation pattern).  Tiles need not divide
+the array: edge blocks of a ragged grid read undefined words past the end,
+the kernel zeroes contraction words past ``k``, and writes past ``m``/``w``
+are dropped — so the VMEM block stays bounded whatever the matrix size.
+
+The body walks the block one contraction *word* at a time (a rolled loop)
+and the word's 32 bits statically.  Mosaic lowers neither a partial
+unroll of that loop nor a dynamic lane index into a loaded value, so the
+word is picked out by a one-hot lane select and a lane reduction, and the
+rhs rows come from an aligned dynamic sublane slice of the ref.
 """
 from __future__ import annotations
 
@@ -27,25 +36,46 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _bitmm_kernel(lhs_ref, rhs_ref, out_ref, *, tk: int):
+def _or_contract(lhs_ref, rhs_ref, acc, *, tk: int, k_words: int):
+    """``acc | (lhs block x rhs block)`` on int32 words: OR into ``acc``
+    (TI, TW) every rhs row whose contraction bit is set in the lhs block."""
+    n_words = tk // 32
+    lane = jax.lax.broadcasted_iota(jnp.int32, (acc.shape[0], n_words), 1)
+    # the last contraction block of a ragged grid runs past the array: its
+    # out-of-range words are undefined and are zeroed here
+    first = pl.program_id(3) * n_words
+    ragged = k_words % n_words != 0
+
+    def word_body(w, acc):
+        lhs = jax.lax.bitcast_convert_type(lhs_ref[0], jnp.int32)
+        word = jnp.sum(jnp.where(lane == w, lhs, 0), axis=1, keepdims=True)
+        if ragged:
+            word = jnp.where(first + w < k_words, word, 0)
+        word = jnp.broadcast_to(word, acc.shape)
+        # the rhs rows of this word's 32 contraction columns
+        rows = jax.lax.bitcast_convert_type(
+            rhs_ref[0, pl.ds(pl.multiple_of(w * 32, 32), 32), :], jnp.int32
+        )
+        for b in range(32):
+            mask = (word << (31 - b)) >> 31  # all-ones where bit b is set
+            acc = acc | (mask & rows[b : b + 1, :])
+        return acc
+
+    return jax.lax.fori_loop(0, n_words, word_body, acc)
+
+
+def _bitmm_kernel(lhs_ref, rhs_ref, out_ref, *, tk: int, k_words: int):
     @pl.when(pl.program_id(3) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    lhs = lhs_ref[0]  # (TI, TK // 32) uint32
-    acc = out_ref[0]  # (TI, TW) uint32
-
-    def body(k, acc):
-        word = lhs[:, k // 32]  # (TI,) uint32 — bits for contraction col k
-        bit = (word >> (k % 32).astype(jnp.uint32)) & jnp.uint32(1)
-        mask = jnp.uint32(0) - bit  # all-ones where the bit is set
-        row = rhs_ref[0, k, :]  # (TW,) uint32
-        return acc | (mask[:, None] & row[None, :])
-
-    out_ref[0] = jax.lax.fori_loop(0, tk, body, acc, unroll=8)
+    acc = jax.lax.bitcast_convert_type(out_ref[0], jnp.int32)
+    acc = _or_contract(lhs_ref, rhs_ref, acc, tk=tk, k_words=k_words)
+    out_ref[0] = jax.lax.bitcast_convert_type(acc, jnp.uint32)
 
 
-def _bitmm_or_kernel(lhs_ref, rhs_ref, acc_ref, out_ref, *, tk: int):
+def _bitmm_or_kernel(lhs_ref, rhs_ref, acc_ref, out_ref, *, tk: int,
+                     k_words: int):
     """Fused C = acc | (lhs x rhs): the closure-step epilogue folded into
     the contraction — the accumulator is read once and or-written in VMEM
     instead of a separate HBM round trip for the union."""
@@ -54,17 +84,32 @@ def _bitmm_or_kernel(lhs_ref, rhs_ref, acc_ref, out_ref, *, tk: int):
     def _init():
         out_ref[...] = acc_ref[...]
 
-    lhs = lhs_ref[0]
-    acc = out_ref[0]
+    acc = jax.lax.bitcast_convert_type(out_ref[0], jnp.int32)
+    acc = _or_contract(lhs_ref, rhs_ref, acc, tk=tk, k_words=k_words)
+    out_ref[0] = jax.lax.bitcast_convert_type(acc, jnp.uint32)
 
-    def body(k, acc):
-        word = lhs[:, k // 32]
-        bit = (word >> (k % 32).astype(jnp.uint32)) & jnp.uint32(1)
-        mask = jnp.uint32(0) - bit
-        row = rhs_ref[0, k, :]
-        return acc | (mask[:, None] & row[None, :])
 
-    out_ref[0] = jax.lax.fori_loop(0, tk, body, acc, unroll=8)
+def _pallas_bitmm(kernel, operands, *, ti, tw, tk, interpret):
+    """Run ``kernel`` over the ragged (B, m/ti, w/tw, k/tk) grid; operands
+    are lhs (B, m, k // 32), rhs (B, k, w) and optionally acc (B, m, w)."""
+    lhs, rhs = operands[:2]
+    B, m, wk = lhs.shape
+    _, k, w = rhs.shape
+    assert rhs.shape[0] == B and wk * 32 == k, (lhs.shape, rhs.shape)
+    assert tk % 32 == 0, tk
+    out_block = pl.BlockSpec((1, ti, tw), lambda b, i, j, kk: (b, i, j))
+    in_specs = [
+        pl.BlockSpec((1, ti, tk // 32), lambda b, i, j, kk: (b, i, kk)),
+        pl.BlockSpec((1, tk, tw), lambda b, i, j, kk: (b, kk, j)),
+    ] + [out_block] * (len(operands) - 2)
+    return pl.pallas_call(
+        functools.partial(kernel, tk=tk, k_words=wk),
+        grid=(B, pl.cdiv(m, ti), pl.cdiv(w, tw), pl.cdiv(k, tk)),
+        in_specs=in_specs,
+        out_specs=out_block,
+        out_shape=jax.ShapeDtypeStruct((B, m, w), jnp.uint32),
+        interpret=interpret,
+    )(*operands)
 
 
 @functools.partial(
@@ -81,24 +126,12 @@ def bitmm_or_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """C = acc | (lhs x rhs) over the AND/OR semiring on packed words."""
-    B, n, w = lhs_packed.shape
-    assert rhs_packed.shape == (B, n, w) and acc_packed.shape == (B, n, w)
-    assert n % ti == 0 and n % tk == 0 and w % tw == 0 and tk % 32 == 0
-
-    grid = (B, n // ti, w // tw, n // tk)
-    kernel = functools.partial(_bitmm_or_kernel, tk=tk)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, ti, tk // 32), lambda b, i, j, k: (b, i, k)),
-            pl.BlockSpec((1, tk, tw), lambda b, i, j, k: (b, k, j)),
-            pl.BlockSpec((1, ti, tw), lambda b, i, j, k: (b, i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, ti, tw), lambda b, i, j, k: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, n, w), jnp.uint32),
-        interpret=interpret,
-    )(lhs_packed, rhs_packed, acc_packed)
+    assert acc_packed.shape == lhs_packed.shape[:2] + rhs_packed.shape[2:]
+    return _pallas_bitmm(
+        _bitmm_or_kernel,
+        (lhs_packed, rhs_packed, acc_packed),
+        ti=ti, tw=tw, tk=tk, interpret=interpret,
+    )
 
 
 @functools.partial(
@@ -117,28 +150,13 @@ def bitmm_pallas(
 
     Shapes: lhs (B, m, k // 32), rhs (B, k, w), out (B, m, w) — rectangular
     row counts are allowed (the query engine contracts a compacted block of
-    m = row_capacity active rows against the full packed state).  ``m`` must
-    divide by ti, the contraction ``k`` by tk, and ``w`` by tw (ops.py picks
-    legal tiles).
+    m = row_capacity active rows against the full packed state).  Tiles
+    need not divide ``m``, ``k`` or ``w`` (see the module docstring); on
+    the TPU they must still meet Mosaic's block rules, which ops.py's
+    ``_pick_tiles`` does.
     """
-    B, m, wk = lhs_packed.shape
-    _, k, w = rhs_packed.shape
-    assert rhs_packed.shape[0] == B and wk * 32 == k, (
-        lhs_packed.shape,
-        rhs_packed.shape,
+    return _pallas_bitmm(
+        _bitmm_kernel,
+        (lhs_packed, rhs_packed),
+        ti=ti, tw=tw, tk=tk, interpret=interpret,
     )
-    assert m % ti == 0 and k % tk == 0 and w % tw == 0 and tk % 32 == 0
-
-    grid = (B, m // ti, w // tw, k // tk)
-    kernel = functools.partial(_bitmm_kernel, tk=tk)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, ti, tk // 32), lambda b, i, j, k: (b, i, k)),
-            pl.BlockSpec((1, tk, tw), lambda b, i, j, k: (b, k, j)),
-        ],
-        out_specs=pl.BlockSpec((1, ti, tw), lambda b, i, j, k: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, m, w), jnp.uint32),
-        interpret=interpret,
-    )(lhs_packed, rhs_packed)

@@ -1,11 +1,15 @@
 """Jitted public wrappers around the Pallas kernels.
 
-``bitmm`` picks legal tile sizes for the input shape and falls back to
-interpret mode off-TPU (this container is CPU-only; interpret mode executes
-the kernel body in Python per grid step, which validates correctness of the
-exact TPU program).
+``bitmm`` picks legal tile sizes for the input shape.  Which program runs
+is decided when the caller is lowered, for the platform it is lowered for
+(``jax.lax.platform_dependent``), never at import: the TPU gets the
+compiled Mosaic kernel; any other backend runs the same kernel body in
+Pallas interpret mode (one Python step per grid element, which validates
+the exact TPU program), or the jnp oracle where interpret mode is too slow.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -13,19 +17,38 @@ import jax.numpy as jnp
 from .bitmm import bitmm_pallas
 from . import ref as _ref
 
-_ON_TPU = jax.default_backend() == "tpu"
-
 #: Above this many packed words per matrix the interpret-mode kernel is too
-#: slow to be useful on CPU; transparently use the jnp oracle instead (the
-#: TPU program is still exercised by the kernel test sweep).
+#: slow to be useful off the TPU; use the jnp oracle instead (the TPU
+#: program is still exercised by the kernel test sweep).
 _INTERPRET_ELEMS_BUDGET = 1 << 22
+
+#: Interpret mode runs one Python step per grid element — for the
+#: block-sparse engine that is one step per *pair*, unpayable inside a
+#: fixpoint loop.  Off the TPU, batches above this size use the jnp
+#: oracle; the Pallas tile program is still exercised by small batches and
+#: the kernel test sweep.
+_TILE_INTERPRET_PAIRS_BUDGET = 16
 
 
 def _pick_tiles(m: int, k: int, w: int) -> tuple[int, int, int]:
-    ti = 128 if m % 128 == 0 else m
-    tw = 128 if w % 128 == 0 else w
-    tk = 4096 if k % 4096 == 0 else k
+    """(ti, tw, tk) meeting Mosaic's block rules — each of a block's last
+    two dims is a multiple of (8, 128) or the whole dim — with VMEM blocks
+    bounded whatever the size: a dim above its tile gets a ragged grid
+    (bitmm.py handles the edge blocks) rather than a whole-dim block."""
+    ti = min(m, 128)
+    tw = min(w, 128)
+    tk = min(k, 4096)
     return ti, tw, tk
+
+
+def _kernel_or_fallback(lhs, rhs, use_oracle: bool):
+    ti, tw, tk = _pick_tiles(lhs.shape[1], rhs.shape[1], rhs.shape[2])
+    kernel = functools.partial(bitmm_pallas, ti=ti, tw=tw, tk=tk)
+    if use_oracle:
+        fallback = _ref.bitmm_ref
+    else:
+        fallback = functools.partial(kernel, interpret=True)
+    return jax.lax.platform_dependent(lhs, rhs, tpu=kernel, default=fallback)
 
 
 def bitmm(lhs_packed: jnp.ndarray, rhs_packed: jnp.ndarray) -> jnp.ndarray:
@@ -35,20 +58,9 @@ def bitmm(lhs_packed: jnp.ndarray, rhs_packed: jnp.ndarray) -> jnp.ndarray:
     block of active rows against the full packed state)."""
     B, m, _ = lhs_packed.shape
     k, w = rhs_packed.shape[-2:]
-    if not _ON_TPU and B * max(m, k) * w > _INTERPRET_ELEMS_BUDGET:
-        return _ref.bitmm_ref(lhs_packed, rhs_packed)
-    ti, tw, tk = _pick_tiles(m, k, w)
-    return bitmm_pallas(
-        lhs_packed, rhs_packed, ti=ti, tw=tw, tk=tk, interpret=not _ON_TPU
+    return _kernel_or_fallback(
+        lhs_packed, rhs_packed, B * max(m, k) * w > _INTERPRET_ELEMS_BUDGET
     )
-
-
-#: Interpret mode runs one Python step per grid element — for the
-#: block-sparse engine that is one step per *pair*, unpayable inside a
-#: fixpoint loop.  Off-TPU, batches above this size use the jnp oracle;
-#: the Pallas tile program is still exercised by small batches and the
-#: kernel test sweep.
-_TILE_INTERPRET_PAIRS_BUDGET = 16
 
 
 def tile_bitmm(lhs_tiles: jnp.ndarray, rhs_tiles: jnp.ndarray) -> jnp.ndarray:
@@ -56,10 +68,6 @@ def tile_bitmm(lhs_tiles: jnp.ndarray, rhs_tiles: jnp.ndarray) -> jnp.ndarray:
     (p, B, B//32) x (p, B, B//32) -> (p, B, B//32), one independent B×B
     product per occupied block pair (the pair axis rides the Pallas grid's
     batch dimension)."""
-    p, B, Bw = lhs_tiles.shape
-    if not _ON_TPU and p > _TILE_INTERPRET_PAIRS_BUDGET:
-        return _ref.bitmm_ref(lhs_tiles, rhs_tiles)
-    ti, tw, tk = _pick_tiles(B, B, Bw)
-    return bitmm_pallas(
-        lhs_tiles, rhs_tiles, ti=ti, tw=tw, tk=tk, interpret=not _ON_TPU
+    return _kernel_or_fallback(
+        lhs_tiles, rhs_tiles, lhs_tiles.shape[0] > _TILE_INTERPRET_PAIRS_BUDGET
     )

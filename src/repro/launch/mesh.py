@@ -11,15 +11,15 @@ state (the dry-run must set XLA_FLAGS before first jax init).
 """
 from __future__ import annotations
 
-import jax
+from repro.shard.mesh import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2):
     """Small mesh for multi-device CPU tests (host-platform devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model))

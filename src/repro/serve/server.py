@@ -454,7 +454,11 @@ class CFPQServer:
         pending = set(tasks) | set(self._inflight)
         while pending:
             await asyncio.gather(*pending, return_exceptions=True)
-            pending = set(self._inflight)
+            # only batches still running: a finished task leaves
+            # _inflight by a done callback that runs on a later loop turn,
+            # and a gather over finished tasks returns without yielding
+            # (Python 3.12), so waiting on it again would spin forever
+            pending = {t for t in self._inflight if not t.done()}
 
     async def stop(self, drain: bool = True) -> None:
         """Stop admitting; drain (default) or cancel what's queued."""

@@ -3,32 +3,9 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.baselines import cyk_recognize
 from repro.core.grammar import CNFGrammar, Production
 from repro.core.graph import Graph
-
-
-def cyk_recognize(g: CNFGrammar, start: str, word: list[str]) -> bool:
-    """Classic CYK over a CNF grammar — used to verify extracted witness
-    paths really derive from the queried nonterminal.  The split-point
-    scan is a NumPy reduction, so long witness strings stay cheap."""
-    n = len(word)
-    if n == 0:
-        return start in g.nullable
-    N = g.n_nonterms
-    tab = np.zeros((n, n + 1, N), dtype=bool)  # [i, j) span
-    for i, x in enumerate(word):
-        for a in g.term_prods.get(x, ()):
-            tab[i, i + 1, a] = True
-    for span in range(2, n + 1):
-        for i in range(0, n - span + 1):
-            j = i + span
-            for a, b, c in g.binary_prods:
-                if not tab[i, j, a]:
-                    # any split k in (i, j): B spans [i, k), C spans [k, j)
-                    tab[i, j, a] = bool(
-                        np.any(tab[i, i + 1 : j, b] & tab[i + 1 : j, j, c])
-                    )
-    return bool(tab[0, n, g.index_of(start)])
 
 
 def assert_path_witness(
@@ -236,15 +213,14 @@ def masked_oracle_run(
     """
     import contextlib
 
-    import jax
     import jax.numpy as jnp
 
     from repro.core.closure import masked_opt_closure
     from repro.core.semantics import masked_opt_single_path_closure
-    from repro.shard.plans import MeshPlan
+    from repro.shard import MeshPlan, make_mesh
 
     if mesh_shape is not None:
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+        mesh = make_mesh(mesh_shape)
         plan = MeshPlan.from_mesh(mesh)
         ctx = mesh
     else:

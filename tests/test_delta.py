@@ -337,9 +337,9 @@ def test_sharded_state_repair_evict_mechanics():
     multi-device meshes is
     tests/test_distributed_masked.py::test_sharded_engine_delta_interleaving
     (whose 1x1 case also runs under tier-1)."""
-    import jax
+    from repro.shard import make_mesh
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     g = query1_grammar().to_cnf()
     graph = ontology_graph(30, 60, seed=1)
     eng = QueryEngine(graph, config=EngineConfig(engine="opt", mesh=mesh))

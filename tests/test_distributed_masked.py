@@ -36,6 +36,7 @@ from repro.engine import (
     Query,
     QueryEngine,
 )
+from repro.shard import make_mesh
 from helpers import (
     assert_path_witness,
     masked_oracle_run,
@@ -68,7 +69,7 @@ PLANS = CompiledClosureCache()
 
 
 def _mesh(shape):
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape)
 
 
 # ---------------------------------------------------------------------- #

@@ -242,6 +242,28 @@ def test_engine_config_validation():
         EngineConfig(row_capacity=0)
 
 
+def test_explicit_axis_mesh_is_refused_up_front():
+    """``with_sharding_constraint`` refuses Explicit mesh axes; the engine
+    says so at construction, not inside the first sharded compile."""
+    import jax
+    from jax.sharding import AxisType
+
+    from repro.shard import make_mesh
+
+    explicit = jax.make_mesh(
+        (1, 1), ("data", "model"), axis_types=(AxisType.Explicit,) * 2
+    )
+    with pytest.raises(ValueError, match="must be Auto"):
+        QueryEngine(
+            paper_example_graph(),
+            config=EngineConfig(engine="opt", mesh=explicit),
+        )
+    QueryEngine(  # the repo's own meshes are Auto
+        paper_example_graph(),
+        config=EngineConfig(engine="opt", mesh=make_mesh((1, 1))),
+    )
+
+
 def test_serve_stats_tally_planner_routes():
     g = query1_grammar().to_cnf()
     eng = QueryEngine(ontology_graph(40, 99, seed=2))
