@@ -19,7 +19,7 @@ Two sections:
            so the conjunct-count multiplier feeding ``PlanFeatures``
            is visible end to end (``...+conjunctive`` routes).
 
-Emits ONE JSON object with --json, shaped for `run.py --aggregate`.
+Emits ONE JSON object with --json.
 """
 from __future__ import annotations
 

@@ -23,7 +23,7 @@ Two sections:
            marginal enumeration cost once the Boolean closure is cached;
            ``index_ms`` is the one-time packing cost after a cold query.
 
-Emits ONE JSON object with --json, shaped for `run.py --aggregate`.
+Emits ONE JSON object with --json.
 """
 from __future__ import annotations
 

@@ -22,7 +22,7 @@ community, power_law) and times, per point,
 Each row also reports the occupied-block fraction, so the crossover is
 attributable: block-sparse wins exactly where occupied_frac collapses
 (large n, low density), and loses to dense where the closure fills in.
-Emits ONE JSON object with --json, shaped for `run.py --aggregate`.
+Emits ONE JSON object with --json.
 """
 from __future__ import annotations
 

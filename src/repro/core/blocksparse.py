@@ -39,7 +39,7 @@ treated as unbounded (the top of the ladder — the host driver has no
 shape reason to cap growth there).
 
 The wrappers below speak the masked-engine contract of core/closure.py
-(``(T, tables, src_mask[, frozen_mask]) -> (T, M, overflow)`` on dense
+(``(T, tables, src_mask[, frozen_mask]) -> (T, M, overflow, iters)`` on dense
 tensors) so ``engine="blocksparse"`` drops into the PlanKey/service
 machinery unchanged; :meth:`BlockSparseState.from_graph` builds the
 state straight from the edge list for the million-node path where the
@@ -347,9 +347,9 @@ def _blocksparse_fixpoint(
     max_iters: int | None,
     use_kernel: bool,
     iter_hook,
-) -> bool:
+) -> tuple[bool, int]:
     """Run the block-sparse closure to fixpoint (or the first capacity
-    overflow) in place; returns the overflow flag.
+    overflow) in place; returns the overflow flag and the iterations run.
 
     ``active``/``to_expand`` carry the seed row-blocks (see
     :func:`_activate`); ``block_open[b]`` is False for blocks whose every
@@ -482,7 +482,7 @@ def _blocksparse_fixpoint(
             if not overflow and to_expand:
                 continue  # a just-allocated block still needs expansion
             break
-    return overflow
+    return overflow, it
 
 
 def _rows_of_blocks(active: set[int], tile: int, n: int) -> np.ndarray:
@@ -517,7 +517,7 @@ def masked_blocksparse_closure(
     iter_hook=None,
 ):
     """Source-restricted block-sparse closure with the standard masked
-    contract: ``(T, M, overflow)``, rows under ``M`` exact at fixpoint,
+    contract: ``(T, M, overflow, iters)``, rows under ``M`` exact at fixpoint,
     monotone partial state + ``overflow=True`` when the occupied-block
     count outgrows ``row_capacity`` (reinterpreted as *block* capacity —
     the service's bucket ladder grows it exactly like row capacities).
@@ -531,7 +531,8 @@ def masked_blocksparse_closure(
     n = T_host.shape[-1]
     _check_tile(n, tile)
     if tables.n_prods == 0:
-        return jnp.asarray(T), jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return (jnp.asarray(T), jnp.ones((n,), jnp.bool_), jnp.bool_(False),
+                jnp.int32(0))
     mask_host = np.asarray(src_mask)
     state = BlockSparseState.from_dense(T_host, tile)
     active: set[int] = set()
@@ -540,7 +541,7 @@ def masked_blocksparse_closure(
     block_open = np.ones(state.grid, dtype=bool)
     for rb in {int(r) // tile for r in np.nonzero(mask_host)[0]}:
         _activate(state, rb, active, to_expand, frontier)
-    overflow = _blocksparse_fixpoint(
+    overflow, iters = _blocksparse_fixpoint(
         state, tables, active, to_expand, block_open,
         row_capacity, max_iters, use_kernel, iter_hook,
     )
@@ -549,6 +550,7 @@ def masked_blocksparse_closure(
         jnp.asarray(state.to_dense()),
         jnp.asarray(M),
         jnp.bool_(overflow),
+        jnp.int32(iters),
     )
 
 
@@ -578,7 +580,8 @@ def masked_blocksparse_repair_closure(
     n = T_host.shape[-1]
     _check_tile(n, tile)
     if tables.n_prods == 0:
-        return jnp.asarray(T), jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return (jnp.asarray(T), jnp.ones((n,), jnp.bool_), jnp.bool_(False),
+                jnp.int32(0))
     frozen_host = np.asarray(frozen_mask)
     seed = np.asarray(src_mask) & ~frozen_host
     state = BlockSparseState.from_dense(T_host, tile)
@@ -588,7 +591,7 @@ def masked_blocksparse_repair_closure(
     frontier: set[int] = set()
     for rb in {int(r) // tile for r in np.nonzero(seed)[0]}:
         _activate(state, rb, active, to_expand, frontier)
-    overflow = _blocksparse_fixpoint(
+    overflow, iters = _blocksparse_fixpoint(
         state, tables, active, to_expand, block_open,
         row_capacity, max_iters, use_kernel, iter_hook,
     )
@@ -597,6 +600,7 @@ def masked_blocksparse_repair_closure(
         jnp.asarray(state.to_dense()),
         jnp.asarray(M),
         jnp.bool_(overflow),
+        jnp.int32(iters),
     )
 
 

@@ -39,6 +39,11 @@ tests/test_engine.py and tests/test_delta.py)
   *against* rows marked frozen but never recompute them: frozen rows of
   the output are bit-identical to the input (the delta subsystem's repair
   contract, asserted exactly in tests/test_delta.py).
+* **Own iteration count.**  Every masked closure returns its
+  ``while_loop`` counter as a fourth output, ``(T, M, overflowed,
+  iters)``: the fixpoint iterations this call ran, one per iteration
+  event of its instrumented build.  The engine reads it only for a live
+  ``closure.execute`` span, so an untraced call reads nothing more back.
 """
 from __future__ import annotations
 
@@ -374,7 +379,7 @@ def masked_closure(
 ):
     """Source-restricted closure on the dense MXU path.
 
-    ``src_mask`` is an (n,) bool row seed.  Returns ``(T, M, overflowed)``;
+    ``src_mask`` is an (n,) bool row seed.  Returns ``(T, M, overflowed, iters)``;
     rows of ``T`` where ``M`` is set equal the all-pairs closure rows iff
     ``overflowed`` is False (otherwise re-enter with the returned state and
     a larger ``row_capacity``).
@@ -382,7 +387,7 @@ def masked_closure(
     n = T.shape[-1]
     if tables.n_prods == 0:
         # T^cf == T0: every row is already exact.
-        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False), jnp.int32(0)
     R = min(row_capacity, n)
     b_idx = jnp.asarray(tables.b_idx, jnp.int32)
     c_idx = jnp.asarray(tables.c_idx, jnp.int32)
@@ -410,8 +415,8 @@ def masked_closure(
         return T | new, M_next, grew, overflow, it + 1
 
     state = (T, src_mask, jnp.bool_(True), jnp.bool_(False), 0)
-    T, M, _, overflow, _ = jax.lax.while_loop(cond, body, state)
-    return T, M, overflow
+    T, M, _, overflow, iters = jax.lax.while_loop(cond, body, state)
+    return T, M, overflow, iters
 
 
 @partial(
@@ -431,7 +436,7 @@ def masked_frontier_closure(
     admitted to the mask enter the delta with their base edges."""
     n = T.shape[-1]
     if tables.n_prods == 0:
-        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False), jnp.int32(0)
     R = min(row_capacity, n)
     b_idx = jnp.asarray(tables.b_idx, jnp.int32)
     c_idx = jnp.asarray(tables.c_idx, jnp.int32)
@@ -462,8 +467,8 @@ def masked_frontier_closure(
 
     D0 = T & src_mask[None, :, None]
     state = (T, D0, src_mask, jnp.bool_(False), 0)
-    T, _, M, overflow, _ = jax.lax.while_loop(cond, body, state)
-    return T, M, overflow
+    T, _, M, overflow, iters = jax.lax.while_loop(cond, body, state)
+    return T, M, overflow, iters
 
 
 @partial(
@@ -487,7 +492,7 @@ def masked_bitpacked_closure(
     entries are a subset of the true closure — and speeds convergence)."""
     n = T.shape[-1]
     if tables.n_prods == 0:
-        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False), jnp.int32(0)
     from repro.kernels import ops as kops
     from repro.kernels import ref as kref
 
@@ -523,8 +528,8 @@ def masked_bitpacked_closure(
         return Tp_next, M_next, grew, overflow, it + 1
 
     state = (Tp0, src_mask, jnp.bool_(True), jnp.bool_(False), 0)
-    Tp, M, _, overflow, _ = jax.lax.while_loop(cond, body, state)
-    return unpack_bits(Tp, n), M, overflow
+    Tp, M, _, overflow, iters = jax.lax.while_loop(cond, body, state)
+    return unpack_bits(Tp, n), M, overflow, iters
 
 
 @partial(
@@ -552,7 +557,7 @@ def masked_opt_closure(
     single device.
 
     Semantics match the other masked engines exactly: returns
-    ``(T, M, overflowed)``; bucket-growth warm restarts are monotone and
+    ``(T, M, overflowed, iters)``; bucket-growth warm restarts are monotone and
     rows already at their fixpoint come back bit-identical regardless of
     the mesh shape (tested in tests/test_distributed_masked.py).
 
@@ -563,7 +568,7 @@ def masked_opt_closure(
     """
     n = T.shape[-1]
     if tables.n_prods == 0:
-        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False), jnp.int32(0)
     R = min(row_capacity, n)
     b_idx = jnp.asarray(tables.b_idx, jnp.int32)
     c_idx = jnp.asarray(tables.c_idx, jnp.int32)
@@ -618,8 +623,8 @@ def masked_opt_closure(
         return Tp_next, M_next, grew, overflow, it + 1
 
     state = (Tp0, src_mask, jnp.bool_(True), jnp.bool_(False), 0)
-    Tp, M, _, overflow, _ = jax.lax.while_loop(cond, body, state)
-    return unpack_bits(Tp, n), M, overflow
+    Tp, M, _, overflow, iters = jax.lax.while_loop(cond, body, state)
+    return unpack_bits(Tp, n), M, overflow, iters
 
 
 # ---------------------------------------------------------------------- #
@@ -711,12 +716,12 @@ def masked_repair_closure(
     """Dense-path repair fixpoint.  ``src_mask`` seeds the rows to rebuild;
     rows under ``frozen_mask`` are trusted exact and never recomputed, but
     join the compacted contraction context (≤ ``ctx_capacity`` rows).
-    Returns ``(T, M, overflowed)`` with ``M`` the rebuilt rows; overflow
+    Returns ``(T, M, overflowed, iters)`` with ``M`` the rebuilt rows; overflow
     fires when either the active set outgrows ``row_capacity`` or the
     context outgrows ``ctx_capacity``."""
     n = T.shape[-1]
     if tables.n_prods == 0:
-        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False), jnp.int32(0)
     R = min(row_capacity, n)
     C = min(ctx_capacity if ctx_capacity is not None else n, n)
     b_idx = jnp.asarray(tables.b_idx, jnp.int32)
@@ -750,8 +755,8 @@ def masked_repair_closure(
         return T | new, M_next, grew, overflow, it + 1
 
     state = (T, src_mask & ~frozen_mask, jnp.bool_(True), jnp.bool_(False), 0)
-    T, M, _, overflow, _ = jax.lax.while_loop(cond, body, state)
-    return T, M, overflow
+    T, M, _, overflow, iters = jax.lax.while_loop(cond, body, state)
+    return T, M, overflow, iters
 
 
 @partial(
@@ -775,7 +780,7 @@ def masked_bitpacked_repair_closure(
     additionally excludes frozen rows from mask expansion)."""
     n = T.shape[-1]
     if tables.n_prods == 0:
-        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False), jnp.int32(0)
     from repro.kernels import ops as kops
     from repro.kernels import ref as kref
 
@@ -817,8 +822,8 @@ def masked_bitpacked_repair_closure(
         jnp.bool_(False),
         0,
     )
-    Tp, M, _, overflow, _ = jax.lax.while_loop(cond, body, state)
-    return unpack_bits(Tp, n), M, overflow
+    Tp, M, _, overflow, iters = jax.lax.while_loop(cond, body, state)
+    return unpack_bits(Tp, n), M, overflow, iters
 
 
 # ---------------------------------------------------------------------- #

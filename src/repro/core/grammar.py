@@ -156,9 +156,12 @@ class Grammar:
                         if p.lhs in reach and p.rhs[0] not in reach:
                             reach.add(p.rhs[0])
                             changed = True
+        # sorted, not set order: the nonterminals' indices, and so the
+        # closure executables and their persistent-cache keys, must not
+        # depend on the process's string hash seed
         out, seen = [], set()
-        for src, reach in unit_reach.items():
-            for tgt in reach:
+        for src, reach in sorted(unit_reach.items()):
+            for tgt in sorted(reach):
                 for p in prods:
                     if p.lhs != tgt:
                         continue

@@ -162,7 +162,7 @@ def masked_single_path_closure(
 
     ``L`` is the (N, n, n) f32 length state (``base_lengths`` of the base
     matrix, or a cached state for a warm restart); ``src_mask`` the (n,)
-    bool row seed.  Returns ``(L, M, overflowed)``; rows of ``L`` under
+    bool row seed.  Returns ``(L, M, overflowed, iters)``; rows of ``L`` under
     ``M`` have ``isfinite(L)`` equal to the all-pairs Boolean closure rows
     iff ``overflowed`` is False (otherwise re-enter with the returned
     state and a larger ``row_capacity`` — the fixpoint is monotone and
@@ -171,7 +171,7 @@ def masked_single_path_closure(
 
     n = L.shape[-1]
     if tables.n_prods == 0:
-        return L, jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return L, jnp.ones((n,), jnp.bool_), jnp.bool_(False), jnp.int32(0)
     R = min(row_capacity, n)
     a_idx = jnp.asarray(tables.a_idx, jnp.int32)
     b_idx = jnp.asarray(tables.b_idx, jnp.int32)
@@ -205,8 +205,8 @@ def masked_single_path_closure(
         return L_next, M_next, grew, overflow, it + 1
 
     state = (L, src_mask, jnp.bool_(True), jnp.bool_(False), 0)
-    L, M, _, overflow, _ = jax.lax.while_loop(cond, body, state)
-    return L, M, overflow
+    L, M, _, overflow, iters = jax.lax.while_loop(cond, body, state)
+    return L, M, overflow, iters
 
 
 @partial(
@@ -232,7 +232,7 @@ def masked_frontier_single_path_closure(
 
     n = L.shape[-1]
     if tables.n_prods == 0:
-        return L, jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return L, jnp.ones((n,), jnp.bool_), jnp.bool_(False), jnp.int32(0)
     R = min(row_capacity, n)
     a_idx = jnp.asarray(tables.a_idx, jnp.int32)
     b_idx = jnp.asarray(tables.b_idx, jnp.int32)
@@ -271,8 +271,8 @@ def masked_frontier_single_path_closure(
 
     D0 = jnp.isfinite(L) & src_mask[None, :, None]
     state = (L, D0, src_mask, jnp.bool_(False), 0)
-    L, _, M, overflow, _ = jax.lax.while_loop(cond, body, state)
-    return L, M, overflow
+    L, _, M, overflow, iters = jax.lax.while_loop(cond, body, state)
+    return L, M, overflow, iters
 
 
 @partial(
@@ -308,13 +308,13 @@ def masked_opt_single_path_closure(
 
     Freeze-on-first-discovery is preserved verbatim (candidates only land
     where ``isfinite(L)`` just flipped), so frozen rows stay bit-identical
-    across warm restarts and mesh shapes; returns ``(L, M, overflowed)``.
+    across warm restarts and mesh shapes; returns ``(L, M, overflowed, iters)``.
     """
     from .closure import _active_rows, _masked_limit
 
     n = L.shape[-1]
     if tables.n_prods == 0:
-        return L, jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return L, jnp.ones((n,), jnp.bool_), jnp.bool_(False), jnp.int32(0)
     R = min(row_capacity, n)
     a_idx = jnp.asarray(tables.a_idx, jnp.int32)
     b_idx = jnp.asarray(tables.b_idx, jnp.int32)
@@ -375,8 +375,8 @@ def masked_opt_single_path_closure(
         return L_next, M_next, grew, overflow, it + 1
 
     state = (L, src_mask, jnp.bool_(True), jnp.bool_(False), 0)
-    L, M, _, overflow, _ = jax.lax.while_loop(cond, body, state)
-    return L, M, overflow
+    L, M, _, overflow, iters = jax.lax.while_loop(cond, body, state)
+    return L, M, overflow, iters
 
 
 @partial(
@@ -402,13 +402,13 @@ def masked_single_path_repair_closure(
     and never recomputed but join the compacted contraction context
     (≤ ``ctx_capacity`` rows), supplying their frozen lengths as constants.
     Served by every backend — lengths are f32, so there is no packed
-    variant to specialize.  Returns ``(L, M, overflowed)``; frozen rows
+    variant to specialize.  Returns ``(L, M, overflowed, iters)``; frozen rows
     come back bit-identical (the scatter only targets active slots)."""
     from .closure import _active_rows, _iter_event, _masked_limit
 
     n = L.shape[-1]
     if tables.n_prods == 0:
-        return L, jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return L, jnp.ones((n,), jnp.bool_), jnp.bool_(False), jnp.int32(0)
     R = min(row_capacity, n)
     C = min(ctx_capacity if ctx_capacity is not None else n, n)
     a_idx = jnp.asarray(tables.a_idx, jnp.int32)
@@ -445,8 +445,8 @@ def masked_single_path_repair_closure(
         return L_next, M_next, grew, overflow, it + 1
 
     state = (L, src_mask & ~frozen_mask, jnp.bool_(True), jnp.bool_(False), 0)
-    L, M, _, overflow, _ = jax.lax.while_loop(cond, body, state)
-    return L, M, overflow
+    L, M, _, overflow, iters = jax.lax.while_loop(cond, body, state)
+    return L, M, overflow, iters
 
 
 # ---------------------------------------------------------------------- #
@@ -518,7 +518,7 @@ def masked_conjunctive_closure(
     ``T`` is the (N, n, n) bool state (``conjunctive.init_matrix`` output
     or a cached state for a warm restart), ``tables`` a
     :class:`~repro.core.conjunctive.ConjunctiveTables`, ``src_mask`` the
-    (n,) bool row seed.  Returns ``(T, M, overflowed)``; rows of ``T``
+    (n,) bool row seed.  Returns ``(T, M, overflowed, iters)``; rows of ``T``
     under ``M`` equal the all-pairs :func:`~repro.core.conjunctive.
     conjunctive_closure` rows iff ``overflowed`` is False (otherwise
     re-enter with the returned state and a larger ``row_capacity``)."""
@@ -526,7 +526,7 @@ def masked_conjunctive_closure(
 
     n = T.shape[-1]
     if tables.n_conjuncts == 0:
-        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False), jnp.int32(0)
     R = min(row_capacity, n)
     b_idx = jnp.asarray(tables.conj_b, jnp.int32)
     c_idx = jnp.asarray(tables.conj_c, jnp.int32)
@@ -553,8 +553,8 @@ def masked_conjunctive_closure(
         return T | new, M_next, grew, overflow, it + 1
 
     state = (T, src_mask, jnp.bool_(True), jnp.bool_(False), 0)
-    T, M, _, overflow, _ = jax.lax.while_loop(cond, body, state)
-    return T, M, overflow
+    T, M, _, overflow, iters = jax.lax.while_loop(cond, body, state)
+    return T, M, overflow, iters
 
 
 @partial(
@@ -586,7 +586,7 @@ def masked_bitpacked_conjunctive_closure(
 
     n = T.shape[-1]
     if tables.n_conjuncts == 0:
-        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return T, jnp.ones((n,), jnp.bool_), jnp.bool_(False), jnp.int32(0)
     from repro.kernels import ops as kops
     from repro.kernels import ref as kref
 
@@ -622,8 +622,8 @@ def masked_bitpacked_conjunctive_closure(
         return Tp_next, M_next, grew, overflow, it + 1
 
     state = (Tp0, src_mask, jnp.bool_(True), jnp.bool_(False), 0)
-    Tp, M, _, overflow, _ = jax.lax.while_loop(cond, body, state)
-    return unpack_bits(Tp, n), M, overflow
+    Tp, M, _, overflow, iters = jax.lax.while_loop(cond, body, state)
+    return unpack_bits(Tp, n), M, overflow, iters
 
 
 # ---------------------------------------------------------------------- #
@@ -882,7 +882,7 @@ def masked_count_closure(
     cached state for a warm restart), ``base`` the current
     :func:`count_base` tensor — the Jacobi recompute needs it as an
     explicit operand, unlike the idempotent algebras.  Returns
-    ``(C, M, overflowed)`` under the standard masked contract: rows of
+    ``(C, M, overflowed, iters)`` under the standard masked contract: rows of
     ``C`` selected by ``M`` equal the all-pairs :func:`count_closure`
     rows iff ``overflowed`` is False.  Masked-row exactness carries over
     from the Boolean argument with sums in place of ORs: every k
@@ -902,7 +902,7 @@ def masked_count_closure(
 
     n = C.shape[-1]
     if tables.n_prods == 0:
-        return C, jnp.ones((n,), jnp.bool_), jnp.bool_(False)
+        return C, jnp.ones((n,), jnp.bool_), jnp.bool_(False), jnp.int32(0)
     R = min(row_capacity, n)
     b_idx = jnp.asarray(tables.b_idx, jnp.int32)
     c_idx = jnp.asarray(tables.c_idx, jnp.int32)
@@ -911,7 +911,7 @@ def masked_count_closure(
 
     # Phase A: Boolean support closure — settles M (and overflow) before
     # any counting happens, so phases B/C run on a fixed active-row set.
-    T_sup, M, overflow = masked_closure(
+    T_sup, M, overflow, _ = masked_closure(
         (C > 0) | (base > 0), tables, src_mask,
         row_capacity=row_capacity, max_iters=max_iters,
     )
@@ -968,8 +968,8 @@ def masked_count_closure(
         return C_next, M_next, grew, overflow, it + 1
 
     state = (C, M, ~overflow, overflow, 0)
-    C, M, _, overflow, _ = jax.lax.while_loop(cond, body, state)
-    return C, M, overflow
+    C, M, _, overflow, iters = jax.lax.while_loop(cond, body, state)
+    return C, M, overflow, iters
 
 
 # ---------------------------------------------------------------------- #
@@ -1168,7 +1168,7 @@ def _masked_allpairs(T: jnp.ndarray, tables: ProductionTables) -> jnp.ndarray:
     from . import closure as _closure
 
     n = T.shape[-1]
-    Tm, _, _ = _closure.masked_closure(
+    Tm, _, _, _ = _closure.masked_closure(
         T, tables, jnp.ones((n,), jnp.bool_), row_capacity=n
     )
     return Tm
@@ -1182,7 +1182,7 @@ def _blocksparse_allpairs(
     from . import blocksparse as _bs
 
     n = T.shape[-1]
-    Tm, _, _ = _bs.masked_blocksparse_closure(
+    Tm, _, _, _ = _bs.masked_blocksparse_closure(
         T, tables, jnp.ones((n,), jnp.bool_), row_capacity=n
     )
     return Tm

@@ -61,6 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import EdgeDelta, Graph
+from repro.obs.trace import NULL_TRACER
 
 
 def localize_state(state_dev):
@@ -82,6 +83,16 @@ def localize_state(state_dev):
     ):
         return jnp.asarray(np.asarray(state_dev))
     return state_dev
+
+
+def mirror_to_host(state_dev, tracer=NULL_TRACER) -> np.ndarray:
+    """The host copy of a cached device state that answers are sliced
+    from, taken under an ``engine.mirror`` span (``bytes``: the state's
+    size).  The copy waits for the state, so the span holds the device to
+    host transfer; a zero-copy view on the CPU backend."""
+    with tracer.span("engine.mirror", cat="engine",
+                     bytes=int(state_dev.nbytes)):
+        return np.asarray(state_dev)
 
 
 def placement_of(state_dev) -> str:
@@ -236,11 +247,15 @@ def _repair_rows(
     base_rows_fn,
     run_closure,
     compose_patch,
+    tracer=NULL_TRACER,
 ) -> tuple[np.ndarray, object, np.ndarray, DeltaStats]:
     """Shared row-surgery flow behind :func:`repair_state` and
     :func:`repair_single_path_state` — the two differ only in how a
     touched row merges with its base row (``compose_patch(old, base, ev)``
     with ``ev`` the evicted-lane mask broadcastable over the patch).
+    ``tracer`` times the host steps: ``repair.base_rows`` (base rows and
+    patch), ``repair.upload`` (the patch's transfer and row scatter, as
+    dispatched) and the final ``engine.mirror``.
 
     1. base surgery on just the touched rows: grow inserted sources' base
        rows, reset evicted rows to the new base (cached entries above them
@@ -261,13 +276,18 @@ def _repair_rows(
     dirty = False
     if touched.any():
         idx = np.nonzero(touched)[0]
-        base = np.asarray(base_rows_fn(idx))  # (|N|, k, n) bool base rows
-        ev = plan.evict[idx][None, :, None]  # evicted reset; inserts grow
-        patch = compose_patch(state_host[:, idx, :], base, ev)
+        with tracer.span("repair.base_rows", cat="engine",
+                         rows=len(idx)) as bsp:
+            base = np.asarray(base_rows_fn(idx))  # (|N|, k, n) base rows
+            ev = plan.evict[idx][None, :, None]  # evicted reset; inserts grow
+            patch = compose_patch(state_host[:, idx, :], base, ev)
+            bsp.set(bytes=int(patch.nbytes))
         stats.rows_evicted = int((mask & plan.evict).sum())
         mask &= ~plan.evict
-        jidx = jnp.asarray(idx.astype(np.int32))
-        state_dev = state_dev.at[:, jidx, :].set(jnp.asarray(patch))
+        with tracer.span("repair.upload", cat="engine",
+                         bytes=int(patch.nbytes)):
+            jidx = jnp.asarray(idx.astype(np.int32))
+            state_dev = state_dev.at[:, jidx, :].set(jnp.asarray(patch))
         dirty = True
 
     seed = (plan.affected & mask) | plan.ins_sources
@@ -281,7 +301,7 @@ def _repair_rows(
         mask |= M
         dirty = True
     if dirty:
-        state_host = np.asarray(state_dev)  # zero-copy view on CPU backend
+        state_host = mirror_to_host(state_dev, tracer)
     return state_host, state_dev, mask, stats
 
 
@@ -292,6 +312,7 @@ def repair_state(
     plan: RepairPlan,
     base_rows_fn,
     run_closure,
+    tracer=NULL_TRACER,
 ) -> tuple[np.ndarray, object, np.ndarray, DeltaStats]:
     """Apply ``plan`` to one grammar's cached Boolean state.
 
@@ -302,7 +323,8 @@ def repair_state(
     ``run_closure(T_dev, seed_mask, frozen_mask) -> (T_dev', M', n_calls)``
     runs the repair fixpoint to completion (handling capacity overflow).
     Both are supplied by the engine so repair stays agnostic of plan
-    caches and backends.  Rows under ``frozen_mask`` are exact on the
+    caches and backends; so is ``tracer``, which times the row surgery
+    (:func:`_repair_rows`).  Rows under ``frozen_mask`` are exact on the
     mutated graph and are contracted against but never recomputed.
 
     Returns ``(T_host, T_dev, mask, stats)``; every returned row under
@@ -313,7 +335,7 @@ def repair_state(
         return np.where(ev, base, old | base)
 
     return _repair_rows(
-        T_host, T_dev, mask, plan, base_rows_fn, run_closure, compose
+        T_host, T_dev, mask, plan, base_rows_fn, run_closure, compose, tracer
     )
 
 
@@ -324,6 +346,7 @@ def repair_single_path_state(
     plan: RepairPlan,
     base_rows_fn,
     run_closure,
+    tracer=NULL_TRACER,
 ) -> tuple[np.ndarray, object, np.ndarray, DeltaStats]:
     """Single-path analog of :func:`repair_state` for cached length states.
 
@@ -353,5 +376,5 @@ def repair_single_path_state(
         return np.where(keep, old, base_l).astype(np.float32)
 
     return _repair_rows(
-        L_host, L_dev, mask, plan, base_rows_fn, run_closure, compose
+        L_host, L_dev, mask, plan, base_rows_fn, run_closure, compose, tracer
     )

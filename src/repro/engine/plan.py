@@ -176,7 +176,7 @@ class PlanKey:
 
     ``repair`` selects the delta-repair variant: same backend, but the
     executable takes an extra frozen-row mask and signature
-    ``(T, src_mask, frozen_mask) -> (T, mask, overflow)``.
+    ``(T, src_mask, frozen_mask) -> (T, mask, overflow, iters)``.
     ``ctx_capacity`` is the repair contraction-context bucket (active plus
     frozen rows) on the dense/frontier backends; 0 when unused.
     ``semantics`` selects the state algebra: ``"relational"`` executables
@@ -189,7 +189,7 @@ class PlanKey:
     executable exactly when their index form coincides.  ``"count"``
     executables run on the (N, n, n) uint32 path-count matrix in the
     saturating semiring and take the base tensor as an extra operand —
-    signature ``(C, base, src_mask) -> (C, mask, overflow)`` — because
+    signature ``(C, base, src_mask) -> (C, mask, overflow, iters)`` — because
     the Jacobi recompute re-adds the base each iteration instead of
     folding it into the state.  Signatures are otherwise identical.
     ``mesh`` is the mesh identity for sharded (``opt``) executables — the
@@ -254,9 +254,10 @@ class PlanStats:
 class CompiledClosureCache:
     """AOT-compiled masked-closure executables keyed on PlanKey.
 
-    ``get(key)`` returns a callable ``(T, src_mask) -> (T, mask, overflow)``
-    with the grammar tables and row capacity baked in; a repeated key never
-    retraces (the executable is reused as-is).
+    ``get(key)`` returns a callable
+    ``(T, src_mask) -> (T, mask, overflow, iters)`` with the grammar
+    tables and row capacity baked in; a repeated key never retraces (the
+    executable is reused as-is).
     """
 
     def __init__(self) -> None:

@@ -69,6 +69,7 @@ from repro.core.semantics import (
 from repro.delta.repair import (
     DeltaStats,
     localize_state,
+    mirror_to_host,
     placement_of,
     plan_repair,
     repair_single_path_state,
@@ -92,6 +93,10 @@ from .plan import (
 )
 from .planner import PlanDecision, PlanFeatures, Planner
 from .stats import QueryStats
+
+
+#: cache states from warmest to coldest (``QueryStats.cache``)
+_CACHE_ORDER = ("hit", "warm", "miss")
 
 
 def grammar_key(g: CNFGrammar | ConjunctiveGrammar):
@@ -333,7 +338,9 @@ class QueryEngine:
         ``batch_total`` (queries submitted together) and ``batch_groups``
         (closure-call groups they were sliced into).
         """
-        with self._lock:
+        with self._lock, self.tracer.span(
+            "engine.read", cat="engine", batch=len(queries)
+        ) as rsp:
             self._check_graph()
             self.clock.validate(snapshot)
             results: list[QueryResult | None] = [None] * len(queries)
@@ -361,6 +368,15 @@ class QueryEngine:
                 out.stats["batch_groups"] = len(groups)  # type: ignore[union-attr]
                 if stats_extra:
                     out.stats.update(stats_extra)  # type: ignore[union-attr]
+            if rsp:
+                rsp.set(
+                    semantics=",".join(sorted({sem for _, sem in groups})),
+                    # the coldest group's cache state names the batch's
+                    cache=max(
+                        (out.stats.cache for out in results),  # type: ignore[union-attr]
+                        key=_CACHE_ORDER.index, default="hit",
+                    ),
+                )
             return results  # type: ignore[return-value]
 
     # ------------------------------------------------------------------ #
@@ -382,12 +398,16 @@ class QueryEngine:
         one repair pass.  Returns this delta's repair stats (the engine
         also accumulates them into every result's stats).
         """
-        with self._lock:
+        insert, delete = list(insert), list(delete)
+        with self._lock, self.tracer.span(
+            "engine.write", cat="engine",
+            inserted=len(insert), deleted=len(delete),
+        ):
             self._check_graph()  # settle pending/out-of-band edits first
             if delete:
-                self.graph.delete_edges(list(delete))
+                self.graph.delete_edges(delete)
             if insert:
-                self.graph.insert_edges(list(insert))
+                self.graph.insert_edges(insert)
             if self.graph.version == self._version:
                 return DeltaStats()  # edits were all no-ops
             return self._ingest_delta()
@@ -408,7 +428,10 @@ class QueryEngine:
                 inserted=len(delta.inserted),
                 deleted=len(delta.deleted),
             ) as dsp:
-                plan = plan_repair(g, delta, self.n)
+                with self.tracer.span("repair.plan", cat="engine") as psp:
+                    plan = plan_repair(g, delta, self.n)
+                    psp.set(evict=int(plan.evict.sum()),
+                            affected=int(plan.affected.sum()))
                 for state in self._states.values():
                     state.extractor = None  # edge indices are stale
                     state.deriv = None  # packed closure index too
@@ -429,7 +452,7 @@ class QueryEngine:
                         T_np = (
                             state.T_host
                             if state.T_host is not None
-                            else np.asarray(state.T)
+                            else mirror_to_host(state.T, self.tracer)
                         )
 
                         def run(T_dev, seed, frozen, tables=state.tables,
@@ -446,7 +469,7 @@ class QueryEngine:
 
                         T_host, T_dev, mask_new, st = repair_state(
                             T_np, state.T, np.asarray(state.mask), plan,
-                            base_rows, run,
+                            base_rows, run, self.tracer,
                         )
                         state.T = T_dev
                         state.T_host = T_host
@@ -465,7 +488,7 @@ class QueryEngine:
                         L_np = (
                             state.sp_L_host
                             if state.sp_L_host is not None
-                            else np.asarray(state.sp_L)
+                            else mirror_to_host(state.sp_L, self.tracer)
                         )
 
                         def run_sp(L_dev, seed, frozen, tables=state.tables,
@@ -483,7 +506,7 @@ class QueryEngine:
 
                         L_host, L_dev, sp_mask, st = repair_single_path_state(
                             L_np, state.sp_L, np.asarray(state.sp_mask), plan,
-                            base_rows, run_sp,
+                            base_rows, run_sp, self.tracer,
                         )
                         state.sp_L = L_dev
                         state.sp_L_host = L_host
@@ -541,7 +564,8 @@ class QueryEngine:
         mask = np.array(state.mask, copy=True)
         state_dev = localize_state(state.T)
         T_host = (
-            state.T_host if state.T_host is not None else np.asarray(state.T)
+            state.T_host if state.T_host is not None
+            else mirror_to_host(state.T, self.tracer)
         )
         if plan.ins_sources.any():
             # base-row surgery: OR the new edges into the inserted-source
@@ -566,7 +590,7 @@ class QueryEngine:
             stats.repair_iters += calls
             stats.conj_repairs += 1
         state.T = state_dev
-        state.T_host = np.asarray(state_dev)
+        state.T_host = mirror_to_host(state_dev, self.tracer)
         state.mask = mask
         state.placement = placement_of(state_dev)
 
@@ -624,7 +648,7 @@ class QueryEngine:
             stats.repair_iters += calls
             stats.count_repairs += 1
         state.cnt_C = C_dev
-        state.cnt_C_host = np.asarray(C_dev)
+        state.cnt_C_host = mirror_to_host(C_dev, self.tracer)
         state.cnt_mask = mask
         state.cnt_placement = placement_of(C_dev)
 
@@ -809,6 +833,10 @@ class QueryEngine:
         the same state).  At most one fallback per run; pinned decisions
         and repairs never fall back.
 
+        With a live ``closure.execute`` span, the executables' own
+        iteration counts are read back and summed over the warm restarts
+        into its ``iterations`` attribute; untraced, nothing more is read.
+
         Returns ``(T_device, M_host, n_calls, fallback_event)``."""
         mask = np.asarray(seed)
         repair = frozen is not None
@@ -861,6 +889,7 @@ class QueryEngine:
             # words instead
             cap_c = bucket_for(max(cap, int(mask.sum()) + n_frozen), self.n)
         calls = 0
+        iters = 0
         fallback_event: dict | None = None
         tracer = self.tracer
         with tracer.span(
@@ -907,15 +936,22 @@ class QueryEngine:
                     tracer.iteration_sink(csp) if instrumented else None
                 ):
                     if repair:
-                        T, M, overflow = exe(T, jnp.asarray(mask), frozen_dev)
+                        T, M, overflow, it = exe(
+                            T, jnp.asarray(mask), frozen_dev
+                        )
                     elif semantics == "count":
                         # counting executables take the base tensor as an
                         # extra operand (the Jacobi recompute re-adds it)
-                        T, M, overflow = exe(T, cnt_base, jnp.asarray(mask))
+                        T, M, overflow, it = exe(
+                            T, cnt_base, jnp.asarray(mask)
+                        )
                     else:
-                        T, M, overflow = exe(T, jnp.asarray(mask))
+                        T, M, overflow, it = exe(T, jnp.asarray(mask))
                     calls += 1
-                    if not bool(overflow):
+                    done = not bool(overflow)
+                    if csp:
+                        iters += int(it)
+                    if done:
                         break
                 mask = np.asarray(M)  # monotone warm restart, larger capacity
                 grown = int(mask.sum())
@@ -974,7 +1010,8 @@ class QueryEngine:
                     active_rows=grown,
                     at_call=calls,
                 )
-            csp.set(calls=calls, active_rows=int(np.asarray(M).sum()))
+            csp.set(calls=calls, active_rows=int(np.asarray(M).sum()),
+                    iterations=iters)
         self.metrics.observe_closure(eng_name, calls)
         return T, np.asarray(M), calls, fallback_event
 
@@ -1031,16 +1068,19 @@ class QueryEngine:
         )
         served = fb["to"] if fb else decision.engine
         if single_path:
-            state.sp_L, state.sp_L_host, state.sp_mask = out, np.asarray(out), M
+            state.sp_L, state.sp_mask = out, M
+            state.sp_L_host = mirror_to_host(out, self.tracer)
             state.sp_placement = placement_of(out)
             state.sp_served_by = served
         elif count:
-            state.cnt_C, state.cnt_C_host = out, np.asarray(out)
+            state.cnt_C = out
+            state.cnt_C_host = mirror_to_host(out, self.tracer)
             state.cnt_mask = M
             state.cnt_placement = placement_of(out)
             state.cnt_served_by = served
         else:
-            state.T, state.T_host, state.mask = out, np.asarray(out), M
+            state.T, state.mask = out, M
+            state.T_host = mirror_to_host(out, self.tracer)
             state.placement = placement_of(out)
             state.served_by = served
             state.deriv = None  # packed index is a view of stale T_host
@@ -1083,15 +1123,19 @@ class QueryEngine:
         stats.update(self.delta_stats.as_dict())
         stats.update(self.plans.stats.as_dict())
         outs = []
-        for q in batch:
-            a0 = state.grammar.index_of(q.start)
-            rows = range(nn) if q.sources is None else q.sources
-            pairs: set[tuple[int, int]] = set()
-            for i in rows:
-                pairs.update((i, int(j)) for j in np.nonzero(T[a0, i, :nn])[0])
-            if q.start in state.grammar.nullable:
-                pairs |= {(m, m) for m in rows}  # empty path m pi m
-            outs.append(QueryResult(q, pairs, None, stats.copy()))
+        with self.tracer.span("engine.slice", cat="engine") as ssp:
+            for q in batch:
+                a0 = state.grammar.index_of(q.start)
+                rows = range(nn) if q.sources is None else q.sources
+                pairs: set[tuple[int, int]] = set()
+                for i in rows:
+                    pairs.update(
+                        (i, int(j)) for j in np.nonzero(T[a0, i, :nn])[0]
+                    )
+                if q.start in state.grammar.nullable:
+                    pairs |= {(m, m) for m in rows}  # empty path m pi m
+                outs.append(QueryResult(q, pairs, None, stats.copy()))
+            ssp.set(pairs=sum(len(o.pairs) for o in outs))
         return outs
 
     def _serve_count(
@@ -1125,24 +1169,26 @@ class QueryEngine:
         stats.update(self.plans.stats.as_dict())
         sat = int(SAT_COUNT)
         outs = []
-        for q in batch:
-            a0 = state.grammar.index_of(q.start)
-            rows = range(nn) if q.sources is None else q.sources
-            pairs: set[tuple[int, int]] = set()
-            counts: dict[tuple[int, int], int] = {}
-            for i in rows:
-                row = C[a0, i, :nn]
-                for j in np.nonzero(row)[0]:
-                    pairs.add((i, int(j)))
-                    counts[(i, int(j))] = int(row[j])
-            if q.start in state.grammar.nullable:
-                for m in rows:  # empty path m pi m is one more path
-                    c = counts.get((m, m), 0)
-                    counts[(m, m)] = c + 1 if c < sat else sat
-                    pairs.add((m, m))
-            outs.append(
-                QueryResult(q, pairs, None, stats.copy(), counts=counts)
-            )
+        with self.tracer.span("engine.slice", cat="engine") as ssp:
+            for q in batch:
+                a0 = state.grammar.index_of(q.start)
+                rows = range(nn) if q.sources is None else q.sources
+                pairs: set[tuple[int, int]] = set()
+                counts: dict[tuple[int, int], int] = {}
+                for i in rows:
+                    row = C[a0, i, :nn]
+                    for j in np.nonzero(row)[0]:
+                        pairs.add((i, int(j)))
+                        counts[(i, int(j))] = int(row[j])
+                if q.start in state.grammar.nullable:
+                    for m in rows:  # empty path m pi m is one more path
+                        c = counts.get((m, m), 0)
+                        counts[(m, m)] = c + 1 if c < sat else sat
+                        pairs.add((m, m))
+                outs.append(
+                    QueryResult(q, pairs, None, stats.copy(), counts=counts)
+                )
+            ssp.set(pairs=sum(len(o.pairs) for o in outs))
         return outs
 
     def extract_paths(
@@ -1185,36 +1231,42 @@ class QueryEngine:
             state, batch, semantics="single_path"
         )
         L = state.sp_L_host
-        if state.extractor is None:  # invalidated on every ingested delta
-            state.extractor = PathExtractor(self.graph, state.grammar)
-        extractor = state.extractor
         nn = self.graph.n_nodes
         # state-scoped memo: repeated/overlapping sources — within a batch
         # or across hot-serve batches — extract each witness exactly once
         # per delta epoch; results get copies so callers can't alias it
         memo = state.sp_paths
         sliced = []
-        for q in batch:
-            a0 = state.grammar.index_of(q.start)
-            rows = range(nn) if q.sources is None else q.sources
-            pairs: set[tuple[int, int]] = set()
-            paths: dict[tuple[int, int], list[tuple[int, str, int]]] = {}
-            for i in rows:
-                for j in np.nonzero(np.isfinite(L[a0, i, :nn]))[0]:
-                    pairs.add((i, int(j)))
-                    key = (q.start, i, int(j))
-                    path = memo.get(key)
-                    if path is None:
-                        path = memo[key] = extractor.extract(
-                            L, q.start, i, int(j)
-                        )
-                    paths[(i, int(j))] = list(path)
-            if q.start in state.grammar.nullable:
-                for m in rows:  # empty path m pi m, as in the relational path
-                    if (m, m) not in pairs:
-                        pairs.add((m, m))
-                        paths[(m, m)] = []
-            sliced.append((q, pairs, paths))
+        looked_up = extracted = 0
+        with self.tracer.span("engine.slice", cat="engine") as ssp:
+            if state.extractor is None:  # invalidated on every ingested delta
+                state.extractor = PathExtractor(self.graph, state.grammar)
+            extractor = state.extractor
+            for q in batch:
+                a0 = state.grammar.index_of(q.start)
+                rows = range(nn) if q.sources is None else q.sources
+                pairs: set[tuple[int, int]] = set()
+                paths: dict[tuple[int, int], list[tuple[int, str, int]]] = {}
+                for i in rows:
+                    for j in np.nonzero(np.isfinite(L[a0, i, :nn]))[0]:
+                        pairs.add((i, int(j)))
+                        key = (q.start, i, int(j))
+                        looked_up += 1
+                        path = memo.get(key)
+                        if path is None:
+                            path = memo[key] = extractor.extract(
+                                L, q.start, i, int(j)
+                            )
+                            extracted += 1
+                        paths[(i, int(j))] = list(path)
+                if q.start in state.grammar.nullable:
+                    for m in rows:  # empty path m pi m, as in relational
+                        if (m, m) not in pairs:
+                            pairs.add((m, m))
+                            paths[(m, m)] = []
+                sliced.append((q, pairs, paths))
+            ssp.set(pairs=sum(len(p) for _, p, _ in sliced),
+                    extracted=extracted, memo_hits=looked_up - extracted)
         # latency includes witness extraction — the dominant per-request
         # host cost on hot serves — not just the closure work
         latency = time.perf_counter() - t0

@@ -24,8 +24,10 @@ from .metrics import (
     SIZE_BUCKETS,
 )
 
-# iteration counts per closure call: warm restarts double capacity, so
-# calls are short; the tail bucket catches pathological grammars
+# executable calls per fixpoint solve (``closure_fixpoint_calls``: the
+# warm-restart ladder's length, 1 when the first capacity bucket held);
+# buckets grow geometrically, so ladders are short and the tail bucket
+# catches pathological grammars
 ITER_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0)
 
 

@@ -24,6 +24,23 @@ Design constraints (OBSERVABILITY.md has the operator story):
   task interleaving — and :meth:`Tracer.wrap` hands a parent span across
   an executor-thread boundary (the serving loop runs engine work in a
   worker thread).
+* **One clock with the device.**  An enabled tracer enters a
+  ``jax.profiler.TraceAnnotation("obs.<name>", span_id=<id>)`` around
+  every context-managed span (:meth:`Tracer.span`), so under a
+  ``jax.profiler`` trace each such span appears on its thread's host line
+  of the xplane, on the clock the device operations are stamped with, and
+  is matched to its :class:`Span` by ``span_id``.  Spans with an explicit
+  lifecycle (:meth:`Tracer.start_span` / :meth:`Tracer.finish`) open and
+  close on different code paths, so they get no annotation.
+
+Named compiles
+--------------
+An enabled tracer turns each of JAX's backend-compile duration events
+(``/jax/core/compile/backend_compile_duration``) into a ``compile`` event
+(``fun_name``, ``seconds``) on the span current where the compile ran.
+One process-wide monitoring listener (:func:`_on_compile`) forwards to
+every live enabled tracer, as :func:`emit_iteration` does for iteration
+events; a disabled tracer registers nothing.
 
 Closure-iteration events
 ------------------------
@@ -43,6 +60,7 @@ import contextvars
 import itertools
 import threading
 import time
+import weakref
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Callable
@@ -103,6 +121,23 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _NullSpanContext:
+    """What a disabled tracer's :meth:`Tracer.span` returns: one shared
+    context manager yielding :data:`NULL_SPAN`, so a disabled span
+    builds no generator and enters nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> _NullSpan:
+        return NULL_SPAN
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NULL_SPAN_CONTEXT = _NullSpanContext()
+
+
 class Tracer:
     """Span/event recorder with an explicit clock.
 
@@ -131,6 +166,8 @@ class Tracer:
         self._current: contextvars.ContextVar[Span | None] = (
             contextvars.ContextVar(f"repro_obs_span_{id(self)}", default=None)
         )
+        if enabled:
+            _watch_compiles(self)
 
     # ------------------------------------------------------------------ #
     @property
@@ -184,7 +221,6 @@ class Tracer:
         span.attrs.update(attrs)
         span.t_end = self.clock() if t_end is None else t_end
 
-    @contextmanager
     def span(
         self,
         name: str,
@@ -194,14 +230,27 @@ class Tracer:
         **attrs,
     ):
         """Context-managed span that is *current* inside the block: nested
-        ``span()`` calls and :meth:`event` attach to it automatically."""
+        ``span()`` calls and :meth:`event` attach to it automatically.
+        Enabled, the block also runs under a profiler annotation
+        ``obs.<name>`` carrying the span's id (module docstring)."""
+        if not self.enabled:
+            return _NULL_SPAN_CONTEXT
+        return self._live_span(name, parent, cat, t_start, attrs)
+
+    @contextmanager
+    def _live_span(self, name, parent, cat, t_start, attrs):
         sp = self.start_span(name, parent=parent, cat=cat, t_start=t_start, **attrs)
-        if not isinstance(sp, Span):
+        if not isinstance(sp, Span):  # over max_spans: dropped
             yield sp
             return
+        import jax
+
         token = self._current.set(sp)
         try:
-            yield sp
+            with jax.profiler.TraceAnnotation(f"obs.{name}", span_id=sp.span_id):
+                if t_start is None:  # stamp beside the annotation's start
+                    sp.t_start = self.clock()
+                yield sp
         finally:
             self._current.reset(token)
             self.finish(sp)
@@ -255,6 +304,38 @@ class Tracer:
     def clear(self) -> None:
         self.spans.clear()
         self.dropped = 0
+
+
+# ---------------------------------------------------------------------- #
+# Named compiles: one process-wide JAX monitoring listener, registered by
+# the first enabled tracer and never removed, forwards each backend
+# compile to every live enabled tracer as a ``compile`` event on the span
+# current in the compiling thread (tracers are held weakly, so a tracer
+# that is gone stops receiving them).
+# ---------------------------------------------------------------------- #
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILE_TRACERS: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_compile_listener_registered = False
+_compile_lock = threading.Lock()
+
+
+def _on_compile(event: str, duration: float, **kw) -> None:
+    if event != COMPILE_EVENT:
+        return
+    for tracer in list(_COMPILE_TRACERS):
+        tracer.event("compile", fun_name=kw.get("fun_name", ""),
+                     seconds=float(duration))
+
+
+def _watch_compiles(tracer: Tracer) -> None:
+    global _compile_listener_registered
+    with _compile_lock:
+        if not _compile_listener_registered:
+            import jax
+
+            jax.monitoring.register_event_duration_secs_listener(_on_compile)
+            _compile_listener_registered = True
+        _COMPILE_TRACERS.add(tracer)
 
 
 #: shared disabled tracer — the default wiring of every engine/server, so

@@ -232,7 +232,7 @@ def masked_oracle_run(
     snapshots = []
     for _ in range(max_restarts):
         with ctx:
-            state, mask, overflow = fn(
+            state, mask, overflow, _ = fn(
                 state, tables, mask, row_capacity=cap, plan=plan
             )
         snapshots.append((np.asarray(state), np.asarray(mask)))

@@ -71,7 +71,7 @@ def _bs_ladder(T0, tables, seed, cap, tile, max_restarts=30):
     while bool(overflow):
         restarts += 1
         assert restarts < max_restarts, "ladder did not terminate"
-        T, M, overflow = masked_blocksparse_closure(
+        T, M, overflow, _ = masked_blocksparse_closure(
             T, tables, np.asarray(M), row_capacity=cap, tile=tile
         )
         cap = min(n, max(2 * cap, 2))
@@ -101,7 +101,7 @@ def test_fixed_seed_differential(tile, seed):
     T0 = init_matrix(graph, g)
     n = T0.shape[-1]
     dense = _allpairs_dense(T0, tables)
-    Tb, Mb, ob = masked_blocksparse_closure(
+    Tb, Mb, ob, _ = masked_blocksparse_closure(
         T0, tables, jnp.ones((n,), jnp.bool_), row_capacity=n, tile=tile
     )
     assert not bool(ob)
@@ -123,7 +123,7 @@ def test_sparse_families_differential(family):
     T0 = init_matrix(graph, g)
     n = T0.shape[-1]
     dense = _allpairs_dense(T0, tables)
-    Tb, _, ob = masked_blocksparse_closure(
+    Tb, _, ob, _ = masked_blocksparse_closure(
         T0, tables, jnp.ones((n,), jnp.bool_), row_capacity=n, tile=32
     )
     assert not bool(ob)
@@ -143,10 +143,10 @@ def test_masked_rows_exact_under_sparse_mask():
         n = T0.shape[-1]
         seed = np.zeros(n, dtype=bool)
         seed[:3] = True
-        Td, Md, _ = closure.masked_closure(
+        Td, Md, _, _ = closure.masked_closure(
             T0, tables, jnp.asarray(seed), row_capacity=n
         )
-        Tb, Mb, ob = masked_blocksparse_closure(
+        Tb, Mb, ob, _ = masked_blocksparse_closure(
             T0, tables, seed, row_capacity=n, tile=32
         )
         assert not bool(ob)
@@ -225,7 +225,7 @@ def test_overflow_returns_monotone_partial_state():
     n = T0.shape[-1]
     seed = np.zeros(n, dtype=bool)
     seed[: graph.n_nodes] = True
-    T1, M1, ov = masked_blocksparse_closure(
+    T1, M1, ov, _ = masked_blocksparse_closure(
         T0, tables, seed, row_capacity=1, tile=32
     )
     assert bool(ov)
@@ -288,7 +288,7 @@ def test_zero_production_grammar_passthrough():
     tables = ProductionTables.from_grammar(g)
     T0 = init_matrix(graph, g)
     n = T0.shape[-1]
-    T, M, ov = masked_blocksparse_closure(
+    T, M, ov, _ = masked_blocksparse_closure(
         T0, tables, np.zeros(n, dtype=bool)
     )
     np.testing.assert_array_equal(np.asarray(T), np.asarray(T0))
@@ -351,10 +351,10 @@ def test_closure_use_kernel_path_matches_oracle_path():
     T0 = init_matrix(graph, g)
     n = T0.shape[-1]
     ones = jnp.ones((n,), jnp.bool_)
-    Tk, _, _ = masked_blocksparse_closure(
+    Tk, _, _, _ = masked_blocksparse_closure(
         T0, tables, ones, row_capacity=n, tile=32, use_kernel=True
     )
-    Tr, _, _ = masked_blocksparse_closure(
+    Tr, _, _, _ = masked_blocksparse_closure(
         T0, tables, ones, row_capacity=n, tile=32, use_kernel=False
     )
     np.testing.assert_array_equal(np.asarray(Tk), np.asarray(Tr))
@@ -496,7 +496,7 @@ def test_blocksparse_repair_mask_excludes_frozen_rows():
     frozen[::2] = True
     seed = np.zeros(n, dtype=bool)
     seed[1:7:2] = True
-    Tb, Mb, ov = masked_blocksparse_repair_closure(
+    Tb, Mb, ov, _ = masked_blocksparse_repair_closure(
         jnp.asarray(full), tables, seed, frozen, row_capacity=n, tile=32
     )
     assert not bool(ov)
@@ -563,7 +563,7 @@ if st is not None:
         T0 = init_matrix(graph, g)
         n = T0.shape[-1]
         dense = _allpairs_dense(T0, tables)
-        Tb, _, ob = masked_blocksparse_closure(
+        Tb, _, ob, _ = masked_blocksparse_closure(
             T0, tables, jnp.ones((n,), jnp.bool_), row_capacity=n, tile=tile
         )
         assert not bool(ob)

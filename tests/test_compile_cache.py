@@ -64,3 +64,17 @@ def test_cache_defaults_to_the_checkout():
     assert where == str(CHECKOUT_CACHE_DIR)
     # written now, or by an earlier run (the key is deterministic)
     assert list(CHECKOUT_CACHE_DIR.glob("jit__lambda-*"))
+
+
+def test_closure_tables_do_not_depend_on_the_hash_seed():
+    # the CNF's nonterminal indices are baked into every closure
+    # executable, so they fix its persistent-cache key
+    code = (
+        "from repro.core.grammar import query1_grammar, query2_grammar\n"
+        "from repro.core.matrices import ProductionTables\n"
+        "for g in (query1_grammar(), query2_grammar()):\n"
+        "    cnf = g.to_cnf()\n"
+        "    print(cnf.nonterms, ProductionTables.from_grammar(cnf))\n"
+    )
+    outs = {_run(code, PYTHONHASHSEED=str(seed)) for seed in range(6)}
+    assert len(outs) == 1, outs

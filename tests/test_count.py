@@ -195,7 +195,7 @@ def test_masked_equals_allpairs_on_mask_rows():
     import jax.numpy as jnp
 
     src = jnp.zeros((n,), bool).at[0].set(True)
-    C_m, M, overflow = masked_count_closure(
+    C_m, M, overflow, _ = masked_count_closure(
         C0, C0, tables, src, row_capacity=n
     )
     assert not bool(overflow)

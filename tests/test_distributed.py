@@ -84,7 +84,7 @@ print("distributed opt closure OK")
     ("relational", True): r"""
 src = np.zeros(n, bool)
 src[[0, 5, 17]] = True
-refT, refM, ovf = closure.masked_closure(
+refT, refM, ovf, _ = closure.masked_closure(
     T0, tables, jnp.asarray(src), row_capacity=n
 )
 assert not bool(ovf)
@@ -94,7 +94,7 @@ for shape in [(2, 1), (4, 2)]:
     mesh = make_test_mesh(*shape)
     plan = MeshPlan.from_mesh(mesh)
     with mesh:
-        T, M, ovf = closure.masked_opt_closure(
+        T, M, ovf, _ = closure.masked_opt_closure(
             T0, tables, jnp.asarray(src), row_capacity=n, plan=plan
         )
     assert not bool(ovf)
@@ -127,11 +127,11 @@ print("distributed single-path closure OK")
     ("single_path", True): r"""
 src = np.zeros(n, bool)
 src[[0, 5, 17]] = True
-refT, refM, _ = closure.masked_closure(
+refT, refM, _, _ = closure.masked_closure(
     T0, tables, jnp.asarray(src), row_capacity=n
 )
 refT, refM = np.asarray(refT), np.asarray(refM)
-refL, refML, ovf = masked_single_path_closure(
+refL, refML, ovf, _ = masked_single_path_closure(
     base_lengths(T0), tables, jnp.asarray(src), row_capacity=n
 )
 assert not bool(ovf)
@@ -139,7 +139,7 @@ for shape in [(2, 1), (4, 2)]:
     mesh = make_test_mesh(*shape)
     plan = MeshPlan.from_mesh(mesh)
     with mesh:
-        L, M, ovf = masked_opt_single_path_closure(
+        L, M, ovf, _ = masked_opt_single_path_closure(
             base_lengths(T0), tables, jnp.asarray(src),
             row_capacity=n, plan=plan,
         )

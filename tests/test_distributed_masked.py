@@ -94,7 +94,7 @@ def test_masked_opt_matches_masked_and_hellings(mesh_shape, seed):
     src = np.zeros(n, bool)
     src[sources] = True
 
-    ref_T, ref_M, ovf = closure.masked_closure(
+    ref_T, ref_M, ovf, _ = closure.masked_closure(
         T0, tables, jnp.asarray(src), row_capacity=n
     )
     assert not bool(ovf)
@@ -130,7 +130,7 @@ def test_masked_opt_single_path_matches_masked_and_oracle(mesh_shape):
     src = np.zeros(n, bool)
     src[[0, 7]] = True
 
-    ref_T, ref_M, _ = closure.masked_closure(
+    ref_T, ref_M, _, _ = closure.masked_closure(
         T0, tables, jnp.asarray(src), row_capacity=n
     )
     ref_T, ref_M = np.asarray(ref_T), np.asarray(ref_M)
@@ -175,7 +175,7 @@ def _assert_ragged_invariants(graph, sources, row_capacity, mesh_shape):
     src = np.zeros(n, bool)
     src[sources] = True
 
-    ref_T, ref_M, ovf = closure.masked_closure(
+    ref_T, ref_M, ovf, _ = closure.masked_closure(
         T0, _RAGGED_TABLES, jnp.asarray(src), row_capacity=n
     )
     assert not bool(ovf)

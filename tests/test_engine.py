@@ -34,7 +34,7 @@ def test_masked_closure_rows_equal_dense_closure(engine):
     for m in range(graph.n_nodes):
         mask = np.zeros(n, bool)
         mask[m] = True
-        T, M, ovf = MASKED_ENGINES[engine](T0, tables, jnp.asarray(mask))
+        T, M, ovf, _ = MASKED_ENGINES[engine](T0, tables, jnp.asarray(mask))
         assert not bool(ovf)
         M = np.asarray(M)
         assert M[m]

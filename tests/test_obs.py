@@ -203,13 +203,171 @@ def _tiny():
     return graph, g
 
 
-def test_disabled_tracer_compiles_uninstrumented_plans_only():
+def test_disabled_tracer_compiles_uninstrumented_plans_only(monkeypatch):
+    import jax
+
+    from repro.obs import trace as obs_trace
+
+    entered, registered = [], []
+    real_annotation = jax.profiler.TraceAnnotation
+
+    def annotation(*a, **k):
+        entered.append(a)
+        return real_annotation(*a, **k)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+    monkeypatch.setattr(jax.monitoring,
+                        "register_event_duration_secs_listener",
+                        registered.append)
     graph, g = _tiny()
     eng = QueryEngine(graph)  # default wiring: NULL_TRACER
     eng.query(Query(g, "S", sources=(1,)))
+    eng.apply_delta(insert=[(0, "subClassOf", 3)])
     assert len(eng.plans) > 0
     assert all(not k.instrumented for k in eng.plans._exe)
     assert eng.tracer.spans == []
+    # no profiler annotation entered, no listener registered or fed
+    off = Tracer(enabled=False)
+    assert entered == [] and registered == []
+    assert off not in obs_trace._COMPILE_TRACERS
+    assert eng.tracer not in obs_trace._COMPILE_TRACERS
+    monkeypatch.undo()  # an enabled tracer registers the real listener
+    # the same PlanKeys as an enabled tracer without iteration events:
+    # the fourth output (the iteration count) adds no executable
+    graph, g = _tiny()  # the same graph, before the write
+    traced = QueryEngine(graph, tracer=Tracer(iteration_events=False))
+    traced.query(Query(g, "S", sources=(1,)))
+    traced.apply_delta(insert=[(0, "subClassOf", 3)])
+    assert set(traced.plans._exe) == set(eng.plans._exe)
+
+
+def test_disabled_span_is_one_shared_context():
+    from repro.obs.trace import NULL_TRACER
+
+    off = Tracer(enabled=False)
+    assert off.span("a") is off.span("b", cat="x", k=1)
+    assert NULL_TRACER.span("c") is off.span("d")
+    with off.span("e") as sp:
+        assert sp is NULL_SPAN
+
+
+def test_enabled_span_enters_a_profiler_annotation(monkeypatch):
+    import jax
+
+    entered = []
+    real_annotation = jax.profiler.TraceAnnotation
+
+    def annotation(name, **kw):
+        entered.append((name, kw))
+        return real_annotation(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+    tr = Tracer()
+    with tr.span("outer") as outer:
+        with tr.span("inner") as inner:
+            pass
+    explicit = tr.start_span("request")  # explicit lifecycle: none
+    tr.finish(explicit)
+    assert entered == [("obs.outer", {"span_id": outer.span_id}),
+                       ("obs.inner", {"span_id": inner.span_id})]
+
+
+def test_compile_event_names_the_jitted_function():
+    import jax
+    import jax.numpy as jnp
+
+    def obs_forced_compile(x):  # a fresh function object: never cached
+        return x * 3 + 1
+
+    tr = Tracer()
+    with tr.span("outer") as outer:
+        jax.jit(obs_forced_compile)(jnp.arange(7)).block_until_ready()
+    names = [ev["args"]["fun_name"] for ev in outer.events
+             if ev["name"] == "compile"]
+    assert any("obs_forced_compile" in n for n in names), outer.events
+    for ev in outer.events:
+        assert ev["args"]["seconds"] >= 0
+
+
+def _span_chain(spans, s):
+    by_id = {x.span_id: x for x in spans}
+    names = []
+    while s.parent_id is not None:
+        s = by_id[s.parent_id]
+        names.append(s.name)
+    return names
+
+
+def test_engine_spans_nest_under_read_and_write():
+    graph, g = _tiny()
+    tr = Tracer(iteration_events=False)
+    eng = QueryEngine(graph, tracer=tr)
+    eng.query(Query(g, "S", sources=(1,)))
+    eng.query(Query(g, "S", sources=(1,), semantics="single_path"))
+    eng.query(Query(g, "S", sources=(1,)))  # a hit: sliced only
+    edge = next(e for e in sorted(graph.edges) if e[1] == "subClassOf")
+    eng.apply_delta(insert=[(2, "type", 5)], delete=[edge])
+    spans = tr.spans
+    assert all(s.t_end is not None for s in spans)
+    reads = [s for s in spans if s.name == "engine.read"]
+    assert [(r.attrs["semantics"], r.attrs["cache"]) for r in reads] == [
+        ("relational", "miss"), ("single_path", "miss"),
+        ("relational", "hit")]
+    assert all(r.parent_id is None and r.attrs["batch"] == 1 for r in reads)
+    writes = [s for s in spans if s.name == "engine.write"]
+    assert len(writes) == 1
+    assert writes[0].attrs == {"inserted": 1, "deleted": 1}
+    for s in spans:
+        chain = _span_chain(spans, s)
+        if s.name == "engine.slice":
+            assert chain == ["engine.read"] and s.attrs["pairs"] >= 1
+        elif s.name == "engine.mirror":
+            assert chain[-1] in ("engine.read", "engine.write")
+            assert s.attrs["bytes"] > 0
+        elif s.name.startswith("repair."):
+            assert chain == ["delta.repair", "engine.write"]
+    slices = [s for s in spans if s.name == "engine.slice"]
+    assert len(slices) == 3
+    assert slices[1].attrs["extracted"] + slices[1].attrs["memo_hits"] >= 1
+    names = {s.name for s in spans}
+    assert {"repair.plan", "repair.base_rows", "repair.upload",
+            "engine.mirror"} <= names
+    plan = next(s for s in spans if s.name == "repair.plan")
+    assert plan.attrs["evict"] >= 1
+    upload = next(s for s in spans if s.name == "repair.upload")
+    base = next(s for s in spans if s.name == "repair.base_rows")
+    assert upload.attrs["bytes"] == base.attrs["bytes"] > 0
+    # a mirror follows every read closure and every repair
+    mirrors = [_span_chain(spans, s) for s in spans
+               if s.name == "engine.mirror"]
+    assert mirrors.count(["engine.read"]) == 2
+    assert mirrors.count(["delta.repair", "engine.write"]) == 2
+
+
+@pytest.mark.parametrize("engine,semantics", [
+    ("dense", "relational"), ("frontier", "relational"),
+    ("bitpacked", "relational"), ("blocksparse", "relational"),
+    ("dense", "single_path"), ("dense", "count"),
+])
+def test_closure_iterations_equal_iteration_events(engine, semantics):
+    from repro.engine import EngineConfig
+
+    runs = {}
+    for events in (True, False):
+        graph, g = _tiny()  # each run writes to its own graph
+        tr = Tracer(iteration_events=events)
+        eng = QueryEngine(graph, config=EngineConfig(engine=engine),
+                          tracer=tr)
+        eng.query(Query(g, "S", sources=(1, 4), semantics=semantics))
+        eng.apply_delta(insert=[(0, "subClassOf", 3)])
+        runs[events] = [s for s in tr.spans if s.name == "closure.execute"]
+    assert runs[True], "a closure ran"
+    for s in runs[True]:
+        ticks = [ev for ev in s.events if ev["name"] == "iteration"]
+        assert s.attrs["iterations"] == len(ticks) > 0
+    # the uninstrumented executables count the same iterations
+    assert [s.attrs["iterations"] for s in runs[False]] == [
+        s.attrs["iterations"] for s in runs[True]]
 
 
 def test_enabled_tracer_requests_instrumented_plans_with_iterations():

@@ -59,8 +59,8 @@ def test_masked_single_path_support_equals_boolean_closure(fn):
     for m in (0, 5, 11):
         src = np.zeros(n, bool)
         src[m] = True
-        L, M, ovf = fn(base_lengths(T0), tables, jnp.asarray(src),
-                       row_capacity=n)
+        L, M, ovf, _ = fn(base_lengths(T0), tables, jnp.asarray(src),
+                          row_capacity=n)
         assert not bool(ovf)
         M = np.asarray(M)
         assert M[m]
@@ -79,12 +79,12 @@ def test_masked_single_path_warm_restart_freezes_lengths():
     n = T0.shape[-1]
     src = np.zeros(n, bool)
     src[0] = True
-    L1, M1, _ = masked_single_path_closure(
+    L1, M1, _, _ = masked_single_path_closure(
         base_lengths(T0), tables, jnp.asarray(src), row_capacity=n
     )
     more = np.asarray(M1).copy()
     more[:graph.n_nodes] = True
-    L2, M2, _ = masked_single_path_closure(
+    L2, M2, _, _ = masked_single_path_closure(
         L1, tables, jnp.asarray(more), row_capacity=n
     )
     L1, L2 = np.asarray(L1), np.asarray(L2)
