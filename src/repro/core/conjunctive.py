@@ -177,9 +177,9 @@ def init_matrix(graph: Graph, g: ConjunctiveGrammar, pad_to: int | None = None):
 def init_matrix_rows(
     graph: Graph, g: ConjunctiveGrammar, rows, pad_to: int | None = None
 ):
-    """Base-matrix rows for a subset of source nodes — the conjunctive
-    analog of :func:`repro.core.matrices.init_matrix_rows`, used by the
-    engine's insert-only delta repair of conjunctive states."""
+    """Base-matrix rows for a subset of source nodes, shape
+    ``(|N|, len(rows), n)``, used by the engine's insert-only delta repair
+    of conjunctive states."""
     import numpy as np
 
     from .matrices import padded_size

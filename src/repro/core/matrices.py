@@ -89,21 +89,19 @@ def init_matrix(
     return jnp.asarray(T)
 
 
-def init_matrix_rows(
-    graph: Graph, g: CNFGrammar, rows, pad_to: int | None = None
-) -> np.ndarray:
-    """Base-matrix rows for a subset of source nodes: the ``rows`` slices
-    of :func:`init_matrix`, shape ``(|N|, len(rows), n)`` — O(|rows|·n)
-    memory instead of O(n²), for delta repair's row surgery."""
-    n = pad_to if pad_to is not None else padded_size(graph.n_nodes)
-    pos = {int(r): k for k, r in enumerate(rows)}
-    out = np.zeros((g.n_nonterms, len(pos), n), dtype=bool)
-    for i, x, j in graph.edges:
-        k = pos.get(i)
-        if k is not None:
-            for a in g.term_prods.get(x, ()):
-                out[a, k, j] = True
-    return out
+def init_matrix_entries(graph: Graph, g: CNFGrammar, rows) -> np.ndarray:
+    """Base-matrix entries of a subset of source nodes: the set entries of
+    :func:`init_matrix`'s ``rows`` slices, as a ``(3, k)`` int32 list of
+    ``(a, i, j)`` — O(edges) memory instead of O(|rows|·n), for delta
+    repair's row surgery (``delta/repair.py:row_surgery``)."""
+    rows = {int(r) for r in rows}
+    out = [
+        (a, i, j)
+        for i, x, j in graph.edges
+        if i in rows
+        for a in g.term_prods.get(x, ())
+    ]
+    return np.asarray(out, dtype=np.int32).reshape(-1, 3).T
 
 
 # ---------------------------------------------------------------------- #

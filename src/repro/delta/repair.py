@@ -55,8 +55,10 @@ frozen row is a no-op.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -75,8 +77,6 @@ def localize_state(state_dev):
     the next sharded query's executable.  Single-device states (including
     everything on non-opt backends) pass through untouched.
     """
-    import jax
-
     if (
         isinstance(state_dev, jax.Array)
         and len(state_dev.sharding.device_set) > 1
@@ -105,8 +105,6 @@ def placement_of(state_dev) -> str:
     state away from where it lives costs a host round-trip, which the
     cost model charges as a "move".
     """
-    import jax
-
     if (
         isinstance(state_dev, jax.Array)
         and len(state_dev.sharding.device_set) > 1
@@ -239,29 +237,73 @@ def plan_repair(graph: Graph, delta: EdgeDelta, pad_to: int) -> RepairPlan:
     return RepairPlan(evict, affected, ins_sources)
 
 
+#: floor of the base-entry buckets: a write's entries are padded to a power
+#: of two at least this long, so the surgery compiles once per bucket.
+ENTRY_BUCKET_MIN = 1024
+
+
+def pad_entries(entries: np.ndarray, n: int) -> np.ndarray:
+    """Pad a ``(3, k)`` int32 ``(a, i, j)`` entry list to its bucket: the
+    next power of two, at least :data:`ENTRY_BUCKET_MIN`.  Padding entries
+    name row ``n``, out of range, so the surgery drops them."""
+    k = entries.shape[1]
+    size = max(ENTRY_BUCKET_MIN, 1 << max(0, k - 1).bit_length())
+    out = np.zeros((3, size), dtype=np.int32)
+    out[1, k:] = n
+    out[:, :k] = entries
+    return out
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def row_surgery(state, evict, entries):
+    """The touched rows' base surgery, on the device, for either state.
+
+    ``state`` is a cached ``(|N|, n, n)`` state, Boolean (relational) or
+    f32 lengths (single-path, picked by dtype); it is donated.  ``evict``
+    is the ``(n,)`` evicted-row mask and ``entries`` the ``(3, K)`` padded
+    base entries ``(a, i, j)`` of every touched row (:func:`pad_entries`).
+    Evicted rows are cleared to absent (``False`` / ``inf``), then every
+    base entry is set: ``True``, or length 1 where the entry is not
+    finite yet — a finite length is never overwritten (the freeze
+    contract), so an inserted source keeps its annotations.  Rows outside
+    the entries and ``evict`` come back unchanged.
+    """
+    lengths = jnp.issubdtype(state.dtype, jnp.floating)
+    absent = jnp.asarray(jnp.inf if lengths else False, state.dtype)
+    state = jnp.where(evict[None, :, None], absent, state)
+    a, i, j = entries
+    if lengths:
+        old = state.at[a, i, j].get(mode="fill", fill_value=jnp.inf)
+        new = jnp.where(jnp.isfinite(old), old, 1.0)
+    else:
+        new = True
+    return state.at[a, i, j].set(new, mode="drop")
+
+
 def _repair_rows(
     state_host: np.ndarray,
     state_dev,
     mask: np.ndarray,
     plan: RepairPlan,
-    base_rows_fn,
+    base_entries_fn,
     run_closure,
-    compose_patch,
     tracer=NULL_TRACER,
 ) -> tuple[np.ndarray, object, np.ndarray, DeltaStats]:
     """Shared row-surgery flow behind :func:`repair_state` and
     :func:`repair_single_path_state` — the two differ only in how a
-    touched row merges with its base row (``compose_patch(old, base, ev)``
-    with ``ev`` the evicted-lane mask broadcastable over the patch).
-    ``tracer`` times the host steps: ``repair.base_rows`` (base rows and
-    patch), ``repair.upload`` (the patch's transfer and row scatter, as
-    dispatched) and the final ``engine.mirror``.
+    touched row merges with its base row, which :func:`row_surgery` picks
+    by the state's dtype.  ``tracer`` times the host steps:
+    ``repair.base_rows`` (the touched rows' base entries), ``repair.upload``
+    (the entries' transfer and the device surgery, as dispatched) and the
+    final ``engine.mirror``.
 
     1. base surgery on just the touched rows: grow inserted sources' base
        rows, reset evicted rows to the new base (cached entries above them
        may derive through a deleted edge; base-only is the sound floor to
-       rebuild from).  The patch is composed host-side and scattered into
-       the device copy — a rows-sized transfer.
+       rebuild from).  The host lists the touched rows' base entries
+       (``base_entries_fn(idx) -> (3, k)`` int32 ``(a, i, j)``), padded to
+       a bucket; :func:`row_surgery` writes them into the device copy —
+       an entries-sized transfer, and one executable per state and bucket.
     2. insertion repair: warm-start the monotone fixpoint from the cached
        state, seeded with the inserted sources plus every still-cached
        ancestor row.  Cached rows outside the ancestor set are FROZEN —
@@ -278,16 +320,16 @@ def _repair_rows(
         idx = np.nonzero(touched)[0]
         with tracer.span("repair.base_rows", cat="engine",
                          rows=len(idx)) as bsp:
-            base = np.asarray(base_rows_fn(idx))  # (|N|, k, n) base rows
-            ev = plan.evict[idx][None, :, None]  # evicted reset; inserts grow
-            patch = compose_patch(state_host[:, idx, :], base, ev)
-            bsp.set(bytes=int(patch.nbytes))
+            base = base_entries_fn(idx)
+            entries = pad_entries(base, len(touched))
+            bsp.set(entries=base.shape[1], bytes=int(entries.nbytes))
         stats.rows_evicted = int((mask & plan.evict).sum())
         mask &= ~plan.evict
         with tracer.span("repair.upload", cat="engine",
-                         bytes=int(patch.nbytes)):
-            jidx = jnp.asarray(idx.astype(np.int32))
-            state_dev = state_dev.at[:, jidx, :].set(jnp.asarray(patch))
+                         bytes=int(entries.nbytes)):
+            state_dev = row_surgery(
+                state_dev, jnp.asarray(plan.evict), jnp.asarray(entries)
+            )
         dirty = True
 
     seed = (plan.affected & mask) | plan.ins_sources
@@ -310,32 +352,33 @@ def repair_state(
     T_dev,
     mask: np.ndarray,
     plan: RepairPlan,
-    base_rows_fn,
+    base_entries_fn,
     run_closure,
     tracer=NULL_TRACER,
 ) -> tuple[np.ndarray, object, np.ndarray, DeltaStats]:
     """Apply ``plan`` to one grammar's cached Boolean state.
 
     ``T_host`` / ``T_dev`` are the host view and device copy of the cached
-    closure; only the rows the plan touches are rebuilt and transferred —
-    never the whole matrix.  ``base_rows_fn(idx) -> (|N|, len(idx), n)``
-    returns the mutated graph's base-matrix rows for a row subset;
+    closure; ``T_host`` is returned as it is when the plan touches nothing,
+    and the surgery never reads it.  Only the touched rows' base entries
+    cross to the device — never a row or the whole matrix: evicted rows
+    become their base row, inserted sources gain their base entries
+    (``old | base``).  ``base_entries_fn(idx) -> (3, k)`` lists the
+    mutated graph's base-matrix entries ``(a, i, j)`` of a row subset
+    (:func:`repro.core.matrices.init_matrix_entries`);
     ``run_closure(T_dev, seed_mask, frozen_mask) -> (T_dev', M', n_calls)``
     runs the repair fixpoint to completion (handling capacity overflow).
     Both are supplied by the engine so repair stays agnostic of plan
     caches and backends; so is ``tracer``, which times the row surgery
     (:func:`_repair_rows`).  Rows under ``frozen_mask`` are exact on the
     mutated graph and are contracted against but never recomputed.
+    ``T_dev`` is donated to the surgery: use the returned copy.
 
     Returns ``(T_host, T_dev, mask, stats)``; every returned row under
     ``mask`` equals the from-scratch closure row on the mutated graph.
     """
-
-    def compose(old, base, ev):
-        return np.where(ev, base, old | base)
-
     return _repair_rows(
-        T_host, T_dev, mask, plan, base_rows_fn, run_closure, compose, tracer
+        T_host, T_dev, mask, plan, base_entries_fn, run_closure, tracer
     )
 
 
@@ -344,7 +387,7 @@ def repair_single_path_state(
     L_dev,
     mask: np.ndarray,
     plan: RepairPlan,
-    base_rows_fn,
+    base_entries_fn,
     run_closure,
     tracer=NULL_TRACER,
 ) -> tuple[np.ndarray, object, np.ndarray, DeltaStats]:
@@ -369,12 +412,6 @@ def repair_single_path_state(
     runs the single-path repair fixpoint (semantics="single_path" through
     the engine's plan cache).  Returns ``(L_host, L_dev, mask, stats)``.
     """
-
-    def compose(old, base, ev):
-        base_l = np.where(base, np.float32(1.0), np.float32(np.inf))
-        keep = np.isfinite(old) & ~ev  # freeze: never overwrite finite
-        return np.where(keep, old, base_l).astype(np.float32)
-
     return _repair_rows(
-        L_host, L_dev, mask, plan, base_rows_fn, run_closure, compose, tracer
+        L_host, L_dev, mask, plan, base_entries_fn, run_closure, tracer
     )

@@ -55,7 +55,7 @@ from repro.core.graph import Graph
 from repro.core.matrices import (
     ProductionTables,
     init_matrix,
-    init_matrix_rows,
+    init_matrix_entries,
     padded_size,
 )
 from repro.core.semantics import (
@@ -445,8 +445,8 @@ class QueryEngine:
                         self._repair_conjunctive(state, delta, plan, stats)
                         continue
 
-                    def base_rows(idx, grammar=state.grammar):
-                        return init_matrix_rows(g, grammar, idx, pad_to=self.n)
+                    def base_entries(idx, grammar=state.grammar):
+                        return init_matrix_entries(g, grammar, idx)
 
                     if state.T is not None and state.mask is not None:
                         T_np = (
@@ -469,7 +469,7 @@ class QueryEngine:
 
                         T_host, T_dev, mask_new, st = repair_state(
                             T_np, state.T, np.asarray(state.mask), plan,
-                            base_rows, run, self.tracer,
+                            base_entries, run, self.tracer,
                         )
                         state.T = T_dev
                         state.T_host = T_host
@@ -506,7 +506,7 @@ class QueryEngine:
 
                         L_host, L_dev, sp_mask, st = repair_single_path_state(
                             L_np, state.sp_L, np.asarray(state.sp_mask), plan,
-                            base_rows, run_sp, self.tracer,
+                            base_entries, run_sp, self.tracer,
                         )
                         state.sp_L = L_dev
                         state.sp_L_host = L_host

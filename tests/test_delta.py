@@ -17,10 +17,15 @@ from repro.core.graph import Graph, ontology_graph, random_labeled_graph
 from repro.core.matrices import (
     ProductionTables,
     init_matrix,
-    init_matrix_rows,
+    init_matrix_entries,
 )
 from repro.core.semantics import evaluate_relational
-from repro.delta.repair import reverse_reach_rows
+from repro.delta.repair import (
+    ENTRY_BUCKET_MIN,
+    pad_entries,
+    reverse_reach_rows,
+    row_surgery,
+)
 from repro.delta.txn import EpochClock, Snapshot, StaleSnapshotError
 from repro.engine import (
     CompiledClosureCache,
@@ -88,12 +93,133 @@ def test_delete_removes_duplicate_occurrences():
 
 
 def test_init_matrix_rows_matches_full_matrix_slices():
+    """The touched rows' base entries are exactly the set entries of the
+    full base matrix's row slices."""
     graph = ontology_graph(20, 40, seed=9)
     g = query1_grammar().to_cnf()
     full = np.asarray(init_matrix(graph, g))
     idx = np.array([0, 3, 17, graph.n_nodes - 1])
-    rows = init_matrix_rows(graph, g, idx, pad_to=full.shape[-1])
-    np.testing.assert_array_equal(rows, full[:, idx, :])
+    a, i, j = init_matrix_entries(graph, g, idx)
+    rows = np.zeros_like(full)
+    rows[a, i, j] = True
+    want = np.zeros_like(full)
+    want[:, idx, :] = full[:, idx, :]
+    np.testing.assert_array_equal(rows, want)
+    assert init_matrix_entries(graph, g, []).shape == (3, 0)
+
+
+# ---------------------------------------------------------------------- #
+# Row surgery on the device (delta/repair.py:row_surgery)
+# ---------------------------------------------------------------------- #
+
+
+def _dense_compose(old, base, ev, lengths):
+    """The dense host compose the device surgery replaces: a touched row
+    merged with its base row (``ev`` broadcast over the evicted rows)."""
+    if not lengths:
+        return np.where(ev, base, old | base)
+    keep = np.isfinite(old) & ~ev  # freeze: never overwrite finite
+    return np.where(keep, old, np.where(base, np.float32(1), np.inf))
+
+
+SURGERY_CASES = {
+    # name: (evicted rows, inserted sources)
+    "evict_only": ([2, 5, 6, 11], []),
+    "insert_only": ([], [1, 9]),
+    "evict_and_insert": ([0, 4, 7, 13], [3, 9]),
+    "inserted_source_evicted": ([3, 8, 12], [3, 10]),
+    "finite_length_kept": ([], [1]),
+    "padding_only": ([15], []),  # no base edge leaves row 15
+}
+
+
+@pytest.mark.parametrize("case", sorted(SURGERY_CASES))
+@pytest.mark.parametrize("semantics", ["relational", "single_path"])
+def test_row_surgery_bit_identical_to_dense_compose(semantics, case):
+    """The device surgery from padded base entries writes exactly what the
+    dense host compose of the touched rows wrote, every other row
+    untouched, for both result algebras."""
+    rng = np.random.default_rng(sorted(SURGERY_CASES).index(case))
+    n, lengths = 16, semantics == "single_path"
+    g = query1_grammar().to_cnf()
+    labels = sorted(g.term_prods)
+    graph = Graph(n, sorted({
+        (int(i), labels[int(x)], int(j))
+        for i, x, j in zip(rng.integers(0, 15, 60),
+                           rng.integers(0, len(labels), 60),
+                           rng.integers(0, n, 60))
+    }))
+    shape = (g.n_nonterms, n, n)
+    if lengths:
+        old = np.where(rng.random(shape) < 0.4,
+                       rng.integers(1, 6, shape), np.inf).astype(np.float32)
+    else:
+        old = rng.random(shape) < 0.4
+    evicted, inserted = SURGERY_CASES[case]
+    evict = np.zeros(n, dtype=bool)
+    evict[evicted] = True
+    touched = evict.copy()
+    touched[inserted] = True
+    idx = np.nonzero(touched)[0]
+    entries = init_matrix_entries(graph, g, idx)
+    if case == "finite_length_kept":
+        a, i, j = entries[:, 0]
+        old[a, i, j] = 3 if lengths else True  # a base entry, annotated
+    if case == "padding_only":
+        assert entries.shape == (3, 0)
+    padded = pad_entries(entries, n)
+    assert padded.shape[1] == ENTRY_BUCKET_MIN
+    assert (padded[1, entries.shape[1]:] == n).all()
+
+    got = np.asarray(row_surgery(
+        jnp.asarray(old), jnp.asarray(evict), jnp.asarray(padded)
+    ))
+    base = np.asarray(init_matrix(graph, g, pad_to=n))[:, idx, :]
+    want = old.copy()
+    want[:, idx, :] = _dense_compose(
+        old[:, idx, :], base, evict[idx][None, :, None], lengths
+    )
+    assert got.dtype == old.dtype
+    np.testing.assert_array_equal(got, want)
+    if case == "finite_length_kept" and lengths:
+        assert got[a, i, j] == 3  # the finite length survives
+
+
+def test_pad_entries_buckets_are_powers_of_two():
+    for k, size in [(0, 1024), (1024, 1024), (1025, 2048), (5000, 8192)]:
+        padded = pad_entries(np.ones((3, k), np.int32), 64)
+        assert padded.shape == (3, size) and padded.dtype == np.int32
+        assert (padded[1, k:] == 64).all() and (padded[:, :k] == 1).all()
+
+
+@pytest.mark.parametrize("semantics", ["relational", "single_path"])
+def test_successive_deletes_compile_the_surgery_once(semantics):
+    """Deletes that evict slightly different row counts reuse one surgery
+    executable: the only compile under ``repair.upload`` is the first
+    delete's ``row_surgery``."""
+    from repro.obs.trace import Tracer
+
+    g = Grammar.from_text("S -> a S | a").to_cnf()
+    n = 40
+    graph = Graph(n, [(k, "a", k + 1) for k in range(n - 1)])
+    tracer = Tracer()
+    eng = QueryEngine(graph, config=EngineConfig(engine="dense"),
+                      tracer=tracer)
+    eng.query(Query(g, "S", semantics=semantics))
+    row_surgery.clear_cache()
+    tracer.clear()
+    for k in (30, 28, 26):  # evicts rows 0..k: 31, 29, 27 rows
+        eng.apply_delta(delete=[(k, "a", k + 1)])
+    plans = [s for s in tracer.spans if s.name == "repair.plan"]
+    assert [s.attrs["evict"] for s in plans] == [31, 29, 27]
+    compiles = [ev["args"]["fun_name"] for s in tracer.spans
+                if s.name == "repair.upload"
+                for ev in s.events if ev["name"] == "compile"]
+    assert len(compiles) == 1 and "row_surgery" in compiles[0], compiles
+    base = [s for s in tracer.spans if s.name == "repair.base_rows"]
+    # two base entries (S and the CNF's `a` nonterminal) per kept edge
+    assert [s.attrs["entries"] for s in base] == [60, 56, 52]
+    assert {s.attrs["bytes"] for s in base} == {3 * 4 * ENTRY_BUCKET_MIN}
 
 
 # ---------------------------------------------------------------------- #

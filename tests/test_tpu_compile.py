@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.closure import masked_closure, masked_opt_closure
 from repro.core.grammar import query2_grammar
 from repro.core.matrices import ProductionTables
+from repro.delta.repair import row_surgery
 from repro.kernels import ops
 from repro.kernels.bitmm import bitmm_or_pallas, bitmm_pallas
 from repro.shard import MeshPlan, make_mesh
@@ -111,6 +112,24 @@ def test_dense_masked_closure_compiles_for_v5e(one_chip, q2_tables):
     assert dots and all("xbf16>" in ln for ln in dots)
     compiled = lowered.compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * GiB
+
+
+# the benchmark's two cached states and their deletes' entry buckets:
+# (5, 16384, 16384) bool with ~12,000 entries, (5, 4096, 4096) f32 lengths
+@pytest.mark.parametrize(
+    "dtype,n,bucket", [(jnp.bool_, 16384, 16384), (jnp.float32, 4096, 4096)]
+)
+def test_row_surgery_compiles_for_v5e(one_chip, dtype, n, bucket):
+    """The write's device row surgery compiles at served sizes, and the
+    donated state is aliased to its output."""
+    state = jax.ShapeDtypeStruct((5, n, n), dtype, sharding=one_chip)
+    evict = jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip)
+    entries = jax.ShapeDtypeStruct((3, bucket), jnp.int32, sharding=one_chip)
+    compiled = row_surgery.lower(state, evict, entries).compile()
+    mem = compiled.memory_analysis()
+    nbytes = 5 * n * n * jnp.dtype(dtype).itemsize
+    assert mem.alias_size_in_bytes == nbytes
+    assert mem.temp_size_in_bytes < 16 * GiB - 2 * nbytes
 
 
 def test_masked_opt_closure_compiles_on_v5e_2x2_mesh(topo, q2_tables):
