@@ -16,6 +16,10 @@ file found by name.  Data: ``bench/configs/<config>.json`` and
   written (``triples``, ``warmup_triple``);
 - ``bench/metrics/<metric>.py``: ``read(run)``, one metric.
 
+A configuration's ``engine`` block may name ``"mesh": [data, model]``:
+the engine then runs sharded over a mesh of exactly the cell's chips
+(:func:`check_layout`, :func:`build_engine`).
+
 This module holds only what every cell shares.  :func:`run_cell` is the
 in-process entry (the tests call it on the CPU with ``need_tpu=False``);
 ``bench/run.py`` is the command.
@@ -70,16 +74,46 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
         raise SetupError(f"no workload {name!r}; have {sorted(by_name)}")
     w = by_name[name]
     (conf,) = [c for c in bench["configs"] if c["name"] == w["config"]]
+    chips = int(w["chips"])
+    config = json.loads((root / conf["file"]).read_text())
+    check_layout(name, chips, config["engine"])
     return Cell(
         name=name,
-        chips=int(w["chips"]),
-        config=json.loads((root / conf["file"]).read_text()),
+        chips=chips,
+        config=config,
         mix=load_mix(w["traffic"], root / "bench" / "traffic"),
         metrics=_metrics_of(bench["end_to_end"], name, ()),
         layer_metrics=_metrics_of(
             bench["per_layer"], name, _metrics_of(bench["end_to_end"], name, ())
         ),
     )
+
+
+def check_layout(cell: str, chips: int, engine: dict) -> None:
+    """A cell on more than one chip runs its engine on a mesh of exactly
+    those chips: the configuration's ``engine`` block names it as
+    ``"mesh": [data, model]``, beside an engine that shards (``opt``, or
+    ``auto``, whose planner may pick it)."""
+    mesh = engine.get("mesh")
+    if mesh is None:
+        if chips > 1:
+            raise SetupError(
+                f"cell {cell!r} asks for {chips} chips, but its "
+                "configuration's engine names no mesh"
+            )
+        return
+    if not (isinstance(mesh, list) and len(mesh) == 2
+            and all(isinstance(k, int) and k > 0 for k in mesh)
+            and math.prod(mesh) == chips):
+        raise SetupError(
+            f"cell {cell!r}: mesh {mesh} is not a [data, model] shape "
+            f"over the cell's {chips} chip(s)"
+        )
+    if engine["engine"] not in ("opt", "auto"):
+        raise SetupError(
+            f"cell {cell!r}: a mesh needs the engine 'opt' or 'auto', "
+            f"not {engine['engine']!r}"
+        )
 
 
 def _metrics_of(entries, cell: str, reported) -> dict[str, dict]:
@@ -119,21 +153,26 @@ def metric_reader(name: str):
     return plugin("metrics", name).read
 
 
-def require_devices(chips: int):
-    """The cell's chips, or :class:`SetupError` naming what JAX found."""
+def require_devices(chips: int, need_tpu: bool = True):
+    """The cell's chips, the first ``chips`` of JAX's devices, or
+    :class:`SetupError` naming what JAX found.  ``need_tpu=False`` takes
+    them from any platform (the tests on the CPU)."""
     import jax
 
     devices = jax.devices()
     platform = devices[0].platform
-    if platform != "tpu":
+    if need_tpu and platform != "tpu":
         raise SetupError(
             f"needs a TPU, but JAX's platform is {platform!r} "
             f"({len(devices)} device(s)); there is no CPU fallback"
         )
     if len(devices) < chips:
-        raise SetupError(f"needs {chips} TPU chip(s), found {len(devices)}")
-    peaks_of(devices[0].device_kind)
-    return devices
+        raise SetupError(
+            f"needs {chips} {platform.upper()} chip(s), found {len(devices)}"
+        )
+    if need_tpu:
+        peaks_of(devices[0].device_kind)
+    return devices[:chips]
 
 
 def peaks_of(kind: str) -> dict:
@@ -255,15 +294,21 @@ class CompileMeter:
 # ---------------------------------------------------------------------- #
 # the system under test
 # ---------------------------------------------------------------------- #
-def build_engine(config: dict, graph):
+def build_engine(config: dict, graph, devices):
+    """The engine of ``config`` over ``graph``; where the configuration
+    names a ``mesh``, sharded over that (data, model) mesh of
+    ``devices``, every axis ``Auto``."""
     from repro.core.graph import Graph
     from repro.engine import EngineConfig, QueryEngine
+    from repro.shard import make_mesh
 
     eng = config["engine"]
+    mesh = (make_mesh(eng["mesh"], devices=devices)
+            if "mesh" in eng else None)
     return QueryEngine(
         Graph(graph.n_nodes, list(graph.edges)),
         config=EngineConfig(
-            engine=eng["engine"], row_capacity=eng["row_capacity"]
+            engine=eng["engine"], mesh=mesh, row_capacity=eng["row_capacity"]
         ),
     )
 
@@ -342,7 +387,7 @@ def run_cell(
     cell = load_cell(name, root)
     import jax
 
-    devices = require_devices(cell.chips) if need_tpu else jax.devices()
+    devices = require_devices(cell.chips, need_tpu)
     marks = {"devices": time.perf_counter()}  # set-up's phases end here
     from repro.compile_cache import enable_compile_cache
 
@@ -370,7 +415,7 @@ def run_cell(
     def query_of(source: int):
         return Query(grammar, start, sources=(source,), semantics=semantics)
 
-    engine = build_engine(cfg, graph)
+    engine = build_engine(cfg, graph, devices)
     if fault is not None:
         faults_mod.install(fault, engine)
     marks["engine"] = time.perf_counter()

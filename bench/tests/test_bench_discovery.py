@@ -10,7 +10,7 @@ import pytest
 from bench.harness import (
     BENCH, ROOT, Run, SetupError, load_cell, metric_reader, peaks_of, plugin,
 )
-from bench.tests.tiny import workloads
+from bench.tests.tiny import mesh_workloads, workloads
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -28,7 +28,7 @@ def test_top_level_keys():
     assert 1 <= SPEC["run_seconds"] <= 51
 
 
-@pytest.mark.parametrize("name", workloads())
+@pytest.mark.parametrize("name", workloads() + mesh_workloads())
 def test_cell_files_resolve(name):
     cell = load_cell(name)
     (w,) = [w for w in SPEC["workloads"] if w["name"] == name]

@@ -47,6 +47,7 @@ def test_tiny_drive_is_correct(tmp_path, restore_jax_config, name):
     assert line["checks"] == {"wrong": {"value": 0, "limit": 0}}
     assert set(line["device"]) == {
         "platform", "kind", "count", "memory_peak_bytes"}
+    assert line["device"]["count"] == 1
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     want = {m["name"] for m in spec["end_to_end"]
             if name in m.get("workloads", [name])}

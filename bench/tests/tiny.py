@@ -1,6 +1,8 @@
 """A small copy of the benchmark for tests on the CPU: the same cells,
 mixes and metrics, with each configuration cut to a few hundred nodes,
-the engine pinned to the dense backend, and a write every few reads."""
+the engine pinned to the dense backend (to ``opt``, the one that
+shards, where the configuration names a mesh), and a write every few
+reads."""
 from __future__ import annotations
 
 import json
@@ -16,7 +18,10 @@ def tiny_root(tmp: Path, rate: float = 40.0, write_every: int = 5) -> Path:
     for conf in bench["configs"]:
         cfg = json.loads((ROOT / conf["file"]).read_text())
         cfg.update(n_classes=60, n_instances=150, n_nodes=256)
-        cfg["engine"].update(engine="dense", row_capacity=64)
+        cfg["engine"].update(
+            engine="opt" if "mesh" in cfg["engine"] else "dense",
+            row_capacity=64,
+        )
         path = tmp / f"{conf['name']}.json"
         path.write_text(json.dumps(cfg))
         conf["file"] = str(path)
@@ -30,6 +35,17 @@ def tiny_root(tmp: Path, rate: float = 40.0, write_every: int = 5) -> Path:
     return tmp
 
 
+def _cells() -> list[dict]:
+    return json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]
+
+
 def workloads() -> list[str]:
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    return [w["name"] for w in bench["workloads"]]
+    """The one-chip cells: they run in-process, on the one CPU device
+    the tests see."""
+    return [w["name"] for w in _cells() if w["chips"] == 1]
+
+
+def mesh_workloads() -> list[str]:
+    """The cells on more than one chip, whose engines run on a mesh: they
+    run in a process of their own, on as many host CPU devices."""
+    return [w["name"] for w in _cells() if w["chips"] > 1]
